@@ -8,6 +8,7 @@ from repro.core import run_in_flare
 from repro.fl import FedAvg, ServerApp, ServerConfig
 from repro.fl.client import ClientApp
 from repro.fl.quickstart import QuickstartClient
+from repro.kernels.platform import enable_compile_cache
 from repro.runtime import FlareRuntime
 
 SITES = ["site-1", "site-2", "site-3"]
@@ -22,6 +23,7 @@ def client_app_fn(site):
 
 
 def main():
+    enable_compile_cache()
     rt = FlareRuntime()
     for s in SITES:
         rt.provision_site(s)

@@ -10,6 +10,7 @@ import time
 from repro.core.interop import _FlowerClientJob, _FlowerServerJob
 from repro.fl import FedAvg, FedAdam, ServerApp, ServerConfig
 from repro.fl.quickstart import make_client_app
+from repro.kernels.platform import enable_compile_cache
 from repro.runtime import FlareRuntime, JobSpec
 
 SITES = ["site-1", "site-2", "site-3"]
@@ -30,6 +31,7 @@ def flower_jobspec(name, strategy, lr):
 
 
 def main():
+    enable_compile_cache()
     rt = FlareRuntime(request_timeout=300.0)
     for s in SITES:
         rt.provision_site(s)

@@ -8,6 +8,7 @@ import numpy as np
 from repro.core import run_in_flare, run_native
 from repro.fl import FedAdam, ServerApp, ServerConfig
 from repro.fl.quickstart import make_client_app
+from repro.kernels.platform import enable_compile_cache
 from repro.runtime import FlareRuntime
 
 SITES = ["site-1", "site-2", "site-3"]
@@ -20,6 +21,7 @@ def make_server_app():
 
 
 def main():
+    enable_compile_cache()
     print("== running the Flower app natively (SuperLink + SuperNodes) ==")
     h_native = run_native(make_server_app(),
                           lambda s: make_client_app(s, lr=0.02, skew=0.2),

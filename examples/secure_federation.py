@@ -10,12 +10,14 @@ from repro.core import run_native
 from repro.fl import (DPMod, FedAvg, SecAggFedAvg, SecAggMod, ServerApp,
                       ServerConfig)
 from repro.fl.quickstart import make_client_app
+from repro.kernels.platform import enable_compile_cache
 from repro.runtime.provision import Provisioner
 
 SITES = ["site-1", "site-2", "site-3"]
 
 
 def main():
+    enable_compile_cache()
     prov = Provisioner("secure-fed-demo", secret=b"\x07" * 32)
     for s in SITES:
         prov.issue(s, "client")

@@ -1,9 +1,10 @@
 """Determinism lint for the aggregation fold and kernel modules.
 
-The fig5 claim is *bitwise* equality across transports/backends, and the
-fold defends it with canonical node order, fp64 accumulation, and a
-runtime fori_loop trip count (kernels/agg_reduce.py docstring).  These
-rules flag the patterns that silently break it:
+The fig5 claim is *bitwise* equality across transports and arrival
+orders, and the fold defends it with canonical node order, fp64
+accumulation on the host, and on the chip a compensated fp32 fold whose
+products are all exact (kernels/agg_reduce.py docstring).  These rules
+flag the patterns that silently break it:
 
 - ``det-set-iter``: iterating a ``set`` (arrival/hash order) in an
   aggregation module — node ids must be sorted before folding;
@@ -13,10 +14,11 @@ rules flag the patterns that silently break it:
 - ``det-float-accum``: builtin ``sum()``/``math.fsum()`` inside a traced
   (jnp/lax/pallas-using) function — Python-float reduction order is
   invisible to the fold's pairing contract;
-- ``det-fori-trip``: a ``fori_loop`` upper bound that the tracer can
-  constant-fold (a literal, or shape arithmetic) — XLA then unrolls the
-  loop and LLVM's reassociation re-enables the FMA contraction the
-  runtime trip count exists to defeat.
+- ``det-fma-split``: Veltkamp's split ``x * (2**s + 1)`` in a kernel
+  module — it is exact only if the product is rounded before the
+  subtraction, and XLA contracts multiply-adds into FMAs per fusion, so
+  the split halves (and every error term built on them) change with the
+  fusion.  Split with a bit mask (``agg_reduce._split``) instead.
 """
 from __future__ import annotations
 
@@ -55,32 +57,9 @@ def _uses_tracing(fn: ast.AST) -> bool:
     return False
 
 
-def _foldable_bound(node: ast.AST) -> bool:
-    """True if the tracer sees this expression as a compile-time constant
-    (literals and array-shape arithmetic; any plain Name keeps it
-    runtime-valued and is accepted)."""
-    if isinstance(node, ast.Constant):
-        return True
-    if isinstance(node, ast.Attribute):
-        return node.attr == "shape" or _foldable_bound(node.value)
-    if isinstance(node, ast.Subscript):
-        # x.shape[0] folds; x[0] on a runtime ref does not
-        return _foldable_bound(node.value) \
-            and isinstance(node.value, (ast.Attribute, ast.Subscript))
-    if isinstance(node, ast.BinOp):
-        return _foldable_bound(node.left) and _foldable_bound(node.right)
-    if isinstance(node, ast.UnaryOp):
-        return _foldable_bound(node.operand)
-    if isinstance(node, ast.Call):
-        f = node.func
-        if isinstance(f, ast.Name) and f.id in ("len", "int"):
-            return all(_foldable_bound(a) for a in node.args)
-    return False
-
-
 class DeterminismCheck(Check):
     rules = ("det-set-iter", "det-entropy", "det-float-accum",
-             "det-fori-trip")
+             "det-fma-split")
 
     def scope(self, mod: Module) -> bool:
         return ("fl" in mod.segments or "kernels" in mod.segments
@@ -91,7 +70,7 @@ class DeterminismCheck(Check):
         yield from self._entropy(mod)
         if mod.basename in _FOLD_BASENAMES or "kernels" in mod.segments:
             yield from self._float_accum(mod)
-            yield from self._fori_trip(mod)
+            yield from self._fma_split(mod)
 
     # ------------------------------------------------------------------
     def _set_iter(self, mod: Module) -> Iterable[Finding]:
@@ -158,24 +137,47 @@ class DeterminismCheck(Check):
                         "det-float-accum", mod.path, node.lineno,
                         node.col_offset,
                         "builtin sum()/math.fsum() inside a traced "
-                        "function accumulates in Python-float order; "
-                        "use the fold's fp64 accumulator (jnp.sum / "
-                        "fori_loop carry)")
+                        "function hides the fold's add order; write the "
+                        "adds out (the compensated pair's order) or use "
+                        "jnp.sum")
 
-    def _fori_trip(self, mod: Module) -> Iterable[Finding]:
+    def _fma_split(self, mod: Module) -> Iterable[Finding]:
         for node in ast.walk(mod.tree):
-            if not isinstance(node, ast.Call) or len(node.args) < 2:
+            if not (isinstance(node, ast.BinOp)
+                    and isinstance(node.op, ast.Mult)):
                 continue
-            f = node.func
-            name = f.attr if isinstance(f, ast.Attribute) else \
-                f.id if isinstance(f, ast.Name) else None
-            if name != "fori_loop":
-                continue
-            if _foldable_bound(node.args[1]):
+            if any(_veltkamp_constant(side)
+                   for side in (node.left, node.right)):
                 yield Finding(
-                    "det-fori-trip", mod.path, node.lineno,
+                    "det-fma-split", mod.path, node.lineno,
                     node.col_offset,
-                    "fori_loop trip count is constant-foldable: XLA "
-                    "unrolls it and LLVM re-enables FMA reassociation "
-                    "(the hazard the runtime n_ref[0] bound defeats) — "
-                    "pass the count through a runtime ref")
+                    "Veltkamp split x * (2**s + 1): FMA contraction "
+                    "(applied per XLA fusion) breaks its exactness — "
+                    "split with a bit mask (agg_reduce._split)")
+
+
+def _const_int(node: ast.AST):
+    if isinstance(node, ast.Constant) and isinstance(node.value, (int, float)):
+        v = node.value
+        return int(v) if float(v).is_integer() else None
+    if isinstance(node, ast.BinOp):
+        a, b = _const_int(node.left), _const_int(node.right)
+        if a is None or b is None:
+            return None
+        if isinstance(node.op, ast.Add):
+            return a + b
+        if isinstance(node.op, ast.Pow) and 0 <= b < 64:
+            return a ** b
+        if isinstance(node.op, ast.LShift) and 0 <= b < 64:
+            return a << b
+    return None
+
+
+def _veltkamp_constant(node: ast.AST) -> bool:
+    """``2**s + 1`` for a split width ``s >= 10`` (1025, 4097, 2**27+1 ...),
+    written as a literal or as constant arithmetic."""
+    v = _const_int(node)
+    if v is None or v < 1025:
+        return False
+    m = v - 1
+    return m & (m - 1) == 0

@@ -756,6 +756,23 @@ class TcpFleetConnection(FleetConnection):
         raise FrameError(f"unexpected frame type {ftype} from server")
 
 
+def start_off_chip(procs) -> None:
+    """Start child processes with ``JAX_PLATFORMS=cpu`` in their
+    environment.  SuperNode children run numpy clients and never need the
+    chip, and a child that reaches for a chip its parent holds fails or
+    hangs (a chip belongs to one process at a time)."""
+    prev = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    try:
+        for p in procs:
+            p.start()
+    finally:
+        if prev is None:
+            del os.environ["JAX_PLATFORMS"]
+        else:
+            os.environ["JAX_PLATFORMS"] = prev
+
+
 def run_supernode(host: str, port: int, node_id: str, client_app_factory,
                   *, run_seconds: float = 120.0,
                   heartbeat_interval: float = 0.5,

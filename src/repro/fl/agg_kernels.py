@@ -45,23 +45,25 @@ numpy everywhere else — overridable with ``REPRO_AGG_BACKEND`` or
 
 - the numpy path is the reference and the default off-TPU; its arithmetic
   is frozen (the fig. 5 bitwise-repro claim rides on it);
-- the Pallas path (:mod:`repro.kernels.agg_reduce`) must agree with it to
-  <=1 ULP of the output leaf dtype for every (kernel, codec) pair — it is
-  bitwise in practice, and `tests/test_agg_pallas.py` enforces the bound
-  across layouts, dtypes, codecs (0xF1/0xF2/0xF3 incl. int8 deltas) and
-  client counts.  Krum's Gram matmul reduction order is hardware-defined,
-  so its *distances* carry a tight relative tolerance instead while the
-  selection and the aggregate stay exact;
+- the Pallas path (:mod:`repro.kernels.agg_reduce`) is fp32-native (the
+  chip has no fp64 vector unit) with a compensated accumulator, and stays
+  within the error bound of ``docs/INVARIANTS.md`` §1 of the numpy fold:
+  <=1 ULP of the fp32 output leaves except under extreme cancellation;
+  `tests/test_agg_pallas.py` enforces it across layouts, codecs
+  (0xF1/0xF2/0xF3 incl. int8 deltas) and client counts.  The median is
+  exact.  Krum's Gram matrix is an fp32 matmul, so its *distances* carry
+  an fp32 relative tolerance while the selection and the aggregate stay
+  within the fold's bound;
 - off-TPU the Pallas kernels run in interpret mode, so CI exercises the
-  real kernel bodies on CPU;
-- payload stacks the Pallas kernels cannot express fall back to numpy
-  silently: non-float domains (SecAgg uint64 shares), clients with
-  heterogeneous codecs/dtypes in one round, mismatched int8 scale
-  windows, or delta payloads with more than one distinct base.  Fallback
-  is per-call, so a single odd client never aborts a round.
+  real kernel bodies on CPU (:mod:`repro.kernels.platform` decides);
+- payloads the Pallas kernels cannot express go to the numpy kernels by
+  one of the written rules in :data:`FALLBACK_RULES`, and every such call
+  is counted (:func:`fallback_counts`) — nothing falls back unseen.
+  Fallback is per-call, so a single odd client never aborts a round.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import os
 import queue
@@ -71,6 +73,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.fl.flat import FlatParams, Layout, memo_token, np_dtype
+from repro.kernels.platform import on_tpu
 
 # 16K elements: chunk fp64 accumulator + scratch = 256 KiB, L2-resident.
 # QCHUNK (int8 scale window) divides CHUNK, so quantized reads stay aligned.
@@ -97,7 +100,7 @@ def default_backend() -> str:
                     f"REPRO_AGG_BACKEND={env!r}; expected one of {BACKENDS}")
             _DEFAULT_BACKEND = env
         else:
-            _DEFAULT_BACKEND = "pallas" if _on_tpu() else "numpy"
+            _DEFAULT_BACKEND = "pallas" if on_tpu() else "numpy"
     return _DEFAULT_BACKEND
 
 
@@ -109,15 +112,6 @@ def set_default_backend(name: Optional[str]) -> None:
     _DEFAULT_BACKEND = name
 
 
-def _on_tpu() -> bool:
-    try:
-        import jax
-
-        return jax.default_backend() == "tpu"
-    except Exception:  # noqa: BLE001 — no jax, no accelerator
-        return False
-
-
 def resolve_backend(backend: Optional[str]) -> str:
     if backend in (None, "auto"):
         return default_backend()
@@ -126,9 +120,61 @@ def resolve_backend(backend: Optional[str]) -> str:
     return backend
 
 
-def _interpret() -> bool:
-    # off-TPU the kernel bodies execute in interpret mode (CPU CI)
-    return not _on_tpu()
+# ---------------------------------------------------------------------------
+# numpy fallback rules of the Pallas backend (counted, never silent)
+# ---------------------------------------------------------------------------
+FALLBACK_RULES = {
+    "float64": "fp64 payloads — fp64 or mixed-dtype layouts and 0xF4 "
+               "partial sums: the chip has no fp64 vector unit, and fp64 "
+               "in must stay fp64-exact",
+    "no_tile": "payloads without a device tile: SecAgg uint64 shares and "
+               "other integer leaves, a delta whose base is not attached, "
+               "an int8 range off a scale-window boundary",
+    "qchunk": "an int8 scale window that is not a multiple of 128 lanes",
+    "mixed": "clients with different codecs or dtypes in one batch call",
+    "multi_base": "delta payloads against more than one base in one "
+                  "batch call",
+}
+_FALLBACK_LOCK = threading.Lock()
+_FALLBACKS: "collections.Counter[str]" = collections.Counter()  # guarded-by: _FALLBACK_LOCK
+
+
+def _fallback(reason: str) -> None:
+    with _FALLBACK_LOCK:
+        _FALLBACKS[reason] += 1
+
+
+def fallback_counts() -> Dict[str, int]:
+    """Process-wide count of Pallas-backend calls that ran on numpy, by
+    :data:`FALLBACK_RULES` reason."""
+    with _FALLBACK_LOCK:
+        return dict(_FALLBACKS)
+
+
+def reset_fallback_counts() -> None:
+    with _FALLBACK_LOCK:
+        _FALLBACKS.clear()
+
+
+def _device_source(fp, lo: int = 0, hi: Optional[int] = None):
+    """``(TileSource, None)`` for a payload the Pallas kernels can fold,
+    else ``(None, reason)`` — a :data:`FALLBACK_RULES` key."""
+    if isinstance(fp, FlatParams) \
+            and fp.layout.uniform_dtype in (None, "float64"):
+        # raw fp64 frames, and mixed layouts (which read as fp64)
+        return None, "float64"
+    ts = getattr(fp, "tile_source", None)
+    src = ts(lo, hi) if ts is not None else None
+    if src is None:
+        return None, "no_tile"
+    if src.kind == "float" and src.data.dtype == np.float64:
+        return None, "float64"
+    if src.kind == "q8":
+        from repro.kernels import agg_reduce
+
+        if agg_reduce.lanes_for(src.qchunk) is None:
+            return None, "qchunk"
+    return src, None
 
 
 def _host_cores() -> int:
@@ -154,33 +200,32 @@ def resolve_shards(shards: Optional[int], mesh=None) -> int:
 
 def _tile_stack(flats: Sequence) -> Optional[Dict[str, Any]]:
     """Stack per-client :class:`~repro.fl.flat.TileSource` adapters into
-    the (C, N) host arrays the Pallas kernels consume, or ``None`` when
-    the round must fall back to numpy (see module docstring)."""
+    the (C, N) host arrays the Pallas kernels consume, or count the
+    :data:`FALLBACK_RULES` reason and return ``None`` (numpy path).  A
+    delta stack's shared base comes back as fp64 for the caller to add
+    (the kernels never see it)."""
     sources = []
     for fp in flats:
-        ts = getattr(fp, "tile_source", None)
-        src = ts() if ts is not None else None
+        src, reason = _device_source(fp)
         if src is None:
+            _fallback(reason)
             return None
         sources.append(src)
     first = sources[0]
-    if any(s.kind != first.kind for s in sources):
+    if any(s.kind != first.kind or s.qchunk != first.qchunk
+           or s.data.dtype != first.data.dtype for s in sources):
+        _fallback("mixed")
         return None
     bases = {id(s.base): s.base for s in sources}
     if len(bases) > 1:
+        _fallback("multi_base")
         return None
     base_obj = next(iter(bases.values()))
-    base = base_obj.to_f64() if base_obj is not None else None
-    if first.kind == "q8":
-        if any(s.qchunk != first.qchunk for s in sources):
-            return None
-        return {"data": np.stack([s.data for s in sources]),
-                "scales": np.stack([s.scales for s in sources]),
-                "qchunk": first.qchunk, "base": base}
-    if any(s.data.dtype != first.data.dtype for s in sources):
-        return None
-    return {"data": np.stack([s.data for s in sources]), "scales": None,
-            "qchunk": 1, "base": base}
+    q8 = first.kind == "q8"
+    return {"data": np.stack([s.data for s in sources]),
+            "scales": np.stack([s.scales for s in sources]) if q8 else None,
+            "qchunk": first.qchunk,
+            "base": base_obj.to_f64() if base_obj is not None else None}
 
 
 def _scatter_leaves(vec: np.ndarray, layout: Layout,
@@ -213,24 +258,89 @@ def weighted_mean(pairs: Sequence[Tuple[FlatParams, float]],
     clients and cast straight into the output buffer, so no total-size fp64
     array is ever materialized.
     """
-    total_w = float(sum(w for _, w in pairs))
-    scaled = [np.float64(w / total_w) for _, w in pairs]
     out = FlatParams.zeros(layout)
-    n = layout.total_size
-    if n == 0 or not pairs:
+    if layout.total_size == 0 or not pairs:
         return out
-    if resolve_backend(backend) == "pallas":
-        stack = _tile_stack([fp for fp, _ in pairs])
-        if stack is not None:
-            from repro.kernels import agg_reduce
-
-            vec = agg_reduce.weighted_sum(
-                stack["data"], np.array(scaled, np.float64),
-                scales=stack["scales"], qchunk=stack["qchunk"],
-                base=stack["base"], block=block, interpret=_interpret())
-            return _vec_to_flat(vec, layout)
+    vec = _weighted_mean_pallas(pairs, backend, block)
+    if vec is not None:
+        return _vec_to_flat(vec, layout)
     uniform = layout.uniform_dtype in _FLOATS
-    ovec = out.math_view() if uniform else np.empty(n, np.float64)
+    ovec = out.math_view() if uniform else np.empty(layout.total_size,
+                                                    np.float64)
+    _weighted_mean_numpy(pairs, ovec)
+    if not uniform:
+        _scatter_leaves(ovec, layout, out)
+    return out
+
+
+def weighted_mean_f64(pairs: Sequence[Tuple[FlatParams, float]],
+                      layout: Layout,
+                      backend: Optional[str] = None) -> np.ndarray:
+    """The fp64 math vector :func:`weighted_mean` rounds into its output
+    leaves — what the on-chip fold is held to against the numpy fold
+    (``docs/INVARIANTS.md`` §1).  The Pallas backend does not fall back
+    here: payloads it cannot fold raise, so a comparison of the two
+    backends never compares numpy with itself."""
+    vec = _weighted_mean_pallas(pairs, backend, None)
+    if vec is None:
+        if resolve_backend(backend) == "pallas":
+            raise ValueError("these payloads have no Pallas fold; see "
+                             "fallback_counts() for the rule")
+        vec = np.empty(layout.total_size, np.float64)
+        _weighted_mean_numpy(pairs, vec)
+    return vec
+
+
+def _scaled_weights(pairs) -> List[np.float64]:
+    total_w = float(sum(w for _, w in pairs))
+    return [np.float64(w / total_w) for _, w in pairs]
+
+
+def _weighted_mean_pallas(pairs, backend: Optional[str],
+                          block: Optional[int]) -> Optional[np.ndarray]:
+    if resolve_backend(backend) != "pallas":
+        return None
+    stack = _tile_stack([fp for fp, _ in pairs])
+    if stack is None:
+        return None
+    from repro.kernels import agg_reduce
+
+    scaled = _scaled_weights(pairs)
+    vec = agg_reduce.weighted_sum(
+        stack["data"], np.array(scaled, np.float64),
+        scales=stack["scales"], qchunk=stack["qchunk"], block=block)
+    if stack["base"] is not None:
+        # deferred delta base: sum s_i (d_i + b) == sum s_i d_i + S b
+        vec += np.float64(sum(scaled)) * stack["base"]
+    return vec
+
+
+def fold_error_bound(pairs: Sequence[Tuple[FlatParams, float]],
+                     layout: Layout) -> np.ndarray:
+    """Per-coordinate bound on how far the Pallas fold's fp64 vector may
+    sit from the numpy fold's (``docs/INVARIANTS.md`` §1):
+    ``C * FOLD_REL_ERR * sum_i |s_i| (|d_i| + |b_i|)`` with ``s_i`` the
+    normalized weights, ``d_i`` the decoded payloads and ``b_i`` a delta's
+    base (0 for a full payload)."""
+    from repro.kernels.agg_reduce import FOLD_REL_ERR
+
+    n = layout.total_size
+    mag = np.zeros(n, np.float64)
+    tmp = np.empty(n, np.float64)
+    bases: Dict[str, list] = {}
+    for (fp, _), s in zip(pairs, _scaled_weights(pairs)):
+        mag += abs(s) * np.abs(fp.decode_chunk(0, n, tmp))
+        base = getattr(fp, "base", None)
+        if getattr(fp, "is_delta", False) and base is not None:
+            bases.setdefault(memo_token(base), [base, 0.0])[1] += abs(s)
+    for base, sw in bases.values():
+        mag += sw * np.abs(base.to_f64())
+    return len(pairs) * FOLD_REL_ERR * mag
+
+
+def _weighted_mean_numpy(pairs, ovec: np.ndarray) -> None:
+    scaled = _scaled_weights(pairs)
+    n = ovec.size
     acc = np.empty(CHUNK, np.float64)
     scratch = np.empty(CHUNK, np.float64)
     tmp = np.empty(CHUNK, np.float64)
@@ -244,9 +354,6 @@ def weighted_mean(pairs: Sequence[Tuple[FlatParams, float]],
             np.multiply(x, sw, out=scratch[:hi - lo])
             a += scratch[:hi - lo]
         ovec[lo:hi] = a
-    if not uniform:
-        _scatter_leaves(ovec, layout, out)
-    return out
 
 
 class _DecodePipeline:
@@ -373,10 +480,11 @@ class StreamingWeightedSum:
     semantics: one fp64 accumulator, every arrival folded through
     ``f64_chunk`` (delta payloads reconstructed per arrival).  On the
     Pallas backend each arrival is one fused dequantize+scale+accumulate
-    kernel launch; the padded device accumulator is **cached across
-    arrivals keyed by the round's codec block geometry** (the common,
+    kernel launch into a compensated fp32 ``(hi, lo)`` device pair, with
+    delta bases deferred to finalize; the padded device pair is **cached
+    across arrivals keyed by the round's tile geometry** (the common,
     codec-homogeneous case after PR 3 negotiation keeps one padded
-    buffer and one async dispatch chain for the whole round), and only a
+    pair and one async dispatch chain for the whole round), and only a
     mixed arrival with different geometry pays the retire + re-pad.
 
     **Sharded (``shards=N`` or ``mesh=...``)** — the round's accumulator
@@ -396,12 +504,12 @@ class StreamingWeightedSum:
     ``overlap`` forces it); on the Pallas backend the same overlap falls
     out of async dispatch — ``out_padded`` accumulator chaining means
     kernel launches return before the device folds, so the host decodes
-    the next arrival while shard kernels run.  On TPU the per-shard
-    kernels use fp32 tiles + an fp64 carry (fp64 VPU is emulated); off-
-    TPU they stay fp64, the bitwise oracle.
+    the next arrival while shard kernels run.
 
     Numerics: the sharded fold is bitwise-invariant across shard counts
-    and overlap on/off (pure elementwise ops in arrival order).  It is
+    and overlap on/off (pure elementwise ops in arrival order, on either
+    backend).  The Pallas fold stays within ``docs/INVARIANTS.md`` §1 of
+    the numpy fold.  The numpy sharded fold is
     bitwise-equal to the single-host mode for non-delta payloads, and
     within ~1 ULP of the fp64 accumulator for delta payloads (the
     deferred base changes the summation grouping) — the same order of
@@ -412,15 +520,10 @@ class StreamingWeightedSum:
     def __init__(self, layout: Layout, backend: Optional[str] = None,
                  block: Optional[int] = None, *,
                  shards: Optional[int] = None, mesh=None,
-                 overlap: Optional[bool] = None,
-                 tile_dtype: Optional[str] = None):
+                 overlap: Optional[bool] = None):
         self.layout = layout
         self.backend = resolve_backend(backend)
         self._block = block
-        # delta-base memo, memo_token(base) -> fp64 materialization.
-        # Tokens are process-unique (never recycled, unlike id()), so the
-        # memo cannot alias a GC'd base and need not pin the object.
-        self._base_memo: Dict[str, np.ndarray] = {}
         self._scratch = np.empty(min(CHUNK, max(layout.total_size, 1)),
                                  np.float64)
         self._tmp = np.empty_like(self._scratch)
@@ -428,14 +531,14 @@ class StreamingWeightedSum:
         self.count = 0
         self.shards = resolve_shards(shards, mesh)
         self.mesh = mesh
-        self._tile_dtype = tile_dtype or (
-            "float32" if _on_tpu() else "float64")
-        # legacy-mode padded device accumulator (geometry-keyed cache)
+        # single-host padded device (hi, lo) pair (geometry-keyed cache)
         self._acc_padded = None
-        self._pad_geom: Optional[Tuple[int, int]] = None
+        self._pad_geom: Optional[Tuple[int, int, int]] = None
         # deferred delta bases: token -> [base object, summed weight].
-        # Sharded dense deltas and sparse deltas (both modes) fold
-        # base-deferred: sum_k w_k (d_k + b) == sum_k w_k d_k + W b
+        # Sharded dense deltas, Pallas dense deltas and sparse deltas fold
+        # base-deferred: sum_k w_k (d_k + b) == sum_k w_k d_k + W b.
+        # Tokens are process-unique (never recycled, unlike id()), and
+        # the entry holds the base object, so no entry can alias another
         self._deferred: Dict[str, list] = {}
         if self.shards:
             from repro.fl.flat import QCHUNK
@@ -446,7 +549,7 @@ class StreamingWeightedSum:
             self._sacc: List[Optional[np.ndarray]] = [
                 np.zeros(hi - lo, np.float64) for lo, hi in self._bounds]
             self._spad: List[Any] = [None] * self.shards
-            self._sgeom: List[Optional[Tuple[int, int]]] = \
+            self._sgeom: List[Optional[Tuple[int, int, int]]] = \
                 [None] * self.shards
             self._devices = (list(mesh.devices.flat)
                              if mesh is not None else None)
@@ -499,6 +602,8 @@ class StreamingWeightedSum:
         ``scale`` (async staleness discount) also multiplies the
         contributed weight: ``total_w += scale * W_e``."""
         sw = np.float64(scale)
+        if self.backend == "pallas":
+            _fallback("float64")        # a raw fp64 subtree sum
         if self.shards:
             if self._pipe is not None:
                 # ride the decode pipeline so the (arrival, shard) fold
@@ -630,50 +735,43 @@ class StreamingWeightedSum:
             return self.layout.total_size * 8
         return max((hi - lo for lo, hi in self._bounds), default=0) * 8
 
-    def _geometry(self, src, n: int) -> Tuple[int, int]:
+    def _geometry(self, src, n: int) -> Tuple[int, int, int]:
         from repro.kernels import agg_reduce
 
-        qc = src.qchunk if src.kind == "q8" else 1
-        blk = self._block or agg_reduce.choose_block(n, qc)
-        if src.kind == "q8":
-            blk = -(-blk // qc) * qc
-        return blk, -(-n // blk) * blk
+        return agg_reduce.geometry(
+            n, 1, src.qchunk if src.kind == "q8" else None, self._block)
 
     # ------------------------------------------------- single-host mode
     def _acc_vec(self) -> np.ndarray:
         """The unpadded single-host accumulator; a live padded device
-        accumulator (geometry cache) is materialized and retired first —
+        pair (geometry cache) is materialized in fp64 and retired first —
         the per-arrival pad+slice fallback for mixed arrivals."""
         if self._acc_padded is not None:
-            n = self.layout.total_size
-            self._acc = np.array(np.asarray(self._acc_padded)[:n])
+            from repro.kernels import agg_reduce
+
+            self._acc = agg_reduce.pair_to_host(self._acc_padded,
+                                                self.layout.total_size)
             self._acc_padded = None
             self._pad_geom = None
         return self._acc
 
     def _add_pallas(self, fp, w: float) -> bool:
-        ts = getattr(fp, "tile_source", None)
-        src = ts() if ts is not None else None
+        src, reason = _device_source(fp)
         if src is None:
+            _fallback(reason)
             return False
-        base = None
-        if src.base is not None:
-            tok = memo_token(src.base)
-            base = self._base_memo.get(tok)
-            if base is None:
-                base = self._base_memo[tok] = src.base.to_f64()
         from repro.kernels import agg_reduce
 
+        self._record_base(fp, w)        # base deferred to finalize
         geom = self._geometry(src, self.layout.total_size)
         if self._pad_geom is not None and self._pad_geom != geom:
             self._acc_vec()         # mixed arrival: retire, re-pad below
         acc = self._acc_padded if self._pad_geom == geom else self._acc
-        out = agg_reduce.weighted_sum(
+        self._acc_padded = agg_reduce.weighted_sum(
             src.data[None, :], np.array([w], np.float64),
             scales=None if src.scales is None else src.scales[None, :],
-            qchunk=src.qchunk, base=base, acc=acc,
-            block=geom[0], interpret=_interpret(), out_padded=True)
-        self._acc_padded, self._pad_geom = out, geom
+            qchunk=src.qchunk, acc=acc, block=self._block, out_padded=True)
+        self._pad_geom = geom
         self._acc = None
         return True
 
@@ -707,8 +805,10 @@ class StreamingWeightedSum:
 
     def _shard_acc(self, si: int) -> np.ndarray:
         if self._spad[si] is not None:
+            from repro.kernels import agg_reduce
+
             lo, hi = self._bounds[si]
-            self._sacc[si] = np.array(np.asarray(self._spad[si])[:hi - lo])
+            self._sacc[si] = agg_reduce.pair_to_host(self._spad[si], hi - lo)
             self._spad[si] = None
             self._sgeom[si] = None
         return self._sacc[si]
@@ -737,20 +837,15 @@ class StreamingWeightedSum:
                 acc[a - lo:b - lo] += self._scratch[:b - a]
 
     def _add_sharded_pallas(self, fp, w: float) -> bool:
-        ts = getattr(fp, "tile_source", None)
-        if ts is None:
-            return False
         live = [(si, lo, hi)
                 for si, (lo, hi) in enumerate(self._bounds) if hi > lo]
         sources = []
-        try:
-            for _, lo, hi in live:
-                src = ts(lo, hi)
-                if src is None:
-                    return False
-                sources.append(src)
-        except TypeError:       # foreign adapter without range support
-            return False
+        for _, lo, hi in live:
+            src, reason = _device_source(fp, lo, hi)
+            if src is None:
+                _fallback(reason)
+                return False
+            sources.append(src)
         from repro.kernels import agg_reduce
 
         wts = np.array([w], np.float64)
@@ -760,27 +855,30 @@ class StreamingWeightedSum:
                 self._shard_acc(si)
             acc = self._spad[si] if self._sgeom[si] == geom \
                 else self._sacc[si]
-            dev = None
             if self._devices:
-                dev = self._devices[si % len(self._devices)]
-            if dev is not None:
                 import jax
 
-                ctx = jax.default_device(dev)
+                ctx = jax.default_device(
+                    self._devices[si % len(self._devices)])
             else:
                 ctx = contextlib.nullcontext()
             with ctx:
-                # base deferred to finalize even when attached (base=None)
-                out = agg_reduce.weighted_sum(
+                # the base was recorded by _add_sharded: deferred
+                self._spad[si] = agg_reduce.weighted_sum(
                     src.data[None, :], wts,
                     scales=None if src.scales is None
                     else src.scales[None, :],
-                    qchunk=src.qchunk, base=None, acc=acc,
-                    block=geom[0], interpret=_interpret(),
-                    out_padded=True, tile_dtype=self._tile_dtype)
-            self._spad[si], self._sgeom[si] = out, geom
+                    qchunk=src.qchunk, acc=acc, block=self._block,
+                    out_padded=True)
+            self._sgeom[si] = geom
             self._sacc[si] = None
         return True
+
+    def shard_devices(self) -> List[Any]:
+        """The device holding each live shard's padded accumulator
+        (``None`` for a shard whose accumulator is on the host)."""
+        return [None if p is None else next(iter(p[0].devices()))
+                for p in self._spad]
 
     def _finalize_sharded(self) -> FlatParams:
         if self._pipe is not None:
@@ -827,7 +925,7 @@ def _rowstack(flats: Sequence[FlatParams], lo: int, hi: int,
 def _sorted_reduce_pallas(flats, layout, kind: str, trim_k: int,
                           block: Optional[int]) -> Optional[FlatParams]:
     """Shared Pallas branch of the sort-based reductions; ``None`` means
-    "fall back to numpy" (unsupported payload stack)."""
+    "fall back to numpy" (counted by :func:`_tile_stack`)."""
     stack = _tile_stack(flats)
     if stack is None:
         return None
@@ -835,8 +933,7 @@ def _sorted_reduce_pallas(flats, layout, kind: str, trim_k: int,
 
     vec = agg_reduce.sort_reduce(
         stack["data"], kind=kind, trim_k=trim_k, scales=stack["scales"],
-        qchunk=stack["qchunk"], base=stack["base"], block=block,
-        interpret=_interpret())
+        qchunk=stack["qchunk"], base=stack["base"], block=block)
     if kind == "trim_sum":
         # numpy's np.mean = sum of rows, then one true divide — doing the
         # divide host-side keeps the rounding identical
@@ -913,10 +1010,10 @@ def krum_distances(flats: Sequence[FlatParams], layout: Layout,
         if stack is not None:
             from repro.kernels import agg_reduce
 
+            # a shared delta base cancels in the row-0 centering
             G = agg_reduce.gram(
                 stack["data"], scales=stack["scales"],
-                qchunk=stack["qchunk"], base=stack["base"], block=block,
-                interpret=_interpret())
+                qchunk=stack["qchunk"], block=block)
             sq = np.diag(G).copy()
             D = sq[:, None] + sq[None, :] - 2.0 * G
             np.maximum(D, 0.0, out=D)

@@ -11,11 +11,12 @@ a jit'd public wrapper in ``ops.py``:
 
 Plus ``agg_reduce``: the server-side aggregation reductions (fused
 weighted sum, sorted median/trimmed tiles, Krum Gram matrix) over flat
-parameter buffers, differential-tested bitwise against the numpy kernels
-in :mod:`repro.fl.agg_kernels` (its reference path and dispatch layer).
+parameter buffers, differential-tested against the numpy kernels in
+:mod:`repro.fl.agg_kernels` (its reference path and dispatch layer).
 
-This container is CPU-only: kernels are VALIDATED with
-``pl.pallas_call(..., interpret=True)`` which executes the kernel body in
-Python; the BlockSpecs/grids are written for real TPU execution.
+On a TPU the kernels compile; elsewhere the same bodies run in interpret
+mode (``repro.kernels.platform`` decides, in one place), which is how the
+CPU test suite checks them.  ``tests/test_chip_compile.py`` compiles the
+main-path kernels for a described v5e chip.
 """
 from repro.kernels import ops, ref  # noqa: F401

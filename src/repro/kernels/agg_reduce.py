@@ -1,387 +1,449 @@
-"""Pallas TPU kernels for flat-buffer FL aggregation (one pass over HBM).
+"""Pallas TPU kernels for flat-buffer FL aggregation (fp32-native).
 
 The numpy kernels in :mod:`repro.fl.agg_kernels` stream every client's
-payload through an L2-blocked fp64 accumulator.  On TPU the same
-reductions become Pallas grid kernels over the (clients x total_params)
-logical matrix, one column-block per grid step, with the wire decode
-**fused into the tile read**:
+payload through an fp64 accumulator; they are the reference and the
+off-TPU path.  A TPU has no fp64 vector unit, so the kernels here run in
+fp32 only — nothing traces under x64 — and keep fp64-grade accuracy with
+error-free transformations.  The wire decode is **fused into the tile
+read**:
 
-- :func:`weighted_sum` — FedAvg's sum(w_i * x_i) (optionally continuing a
-  running accumulator, the streaming arrival-order fold).  Per block:
-  dequantize(+delta-base) + scale into an fp32->fp64 tile, then fold the
-  client rows sequentially.
-- :func:`sort_reduce` — coordinate-wise median / trimmed sum on the
-  sorted (clients, block) tile (the host divides a trimmed *sum* by the
-  row count so the final divide matches numpy's ``np.mean`` bitwise).
-- :func:`gram` — the Krum Gram matrix: each tile is centered on its first
-  row and accumulated as ``G += t @ t.T`` across grid steps (MXU matmul,
-  fp64 accumulation).
+- :func:`weighted_sum` — FedAvg's ``sum(w_i * x_i)``, optionally
+  continuing a running accumulator (the streaming arrival-order fold).
+  The accumulator is a compensated fp32 pair ``(hi, lo)``: ``x`` is split
+  into 12-bit halves by a bit mask and ``w`` arrives as four 12-bit fp32
+  words, so ``x * w`` is a sum of eight *exact* partial products, folded
+  with TwoSum.  The fp64 value ``hi + lo`` is formed on the host only when
+  the fold is read.  No rounded product ever feeds an add, so FMA
+  contraction (which XLA:CPU applies per fusion) cannot change a bit.
+- :func:`sort_reduce` — coordinate-wise median / trimmed sum.  A Batcher
+  sorting network of min/max over the client rows sorts each tile; the
+  median returns the two middle rows (the host applies numpy's own fp64
+  median arithmetic), the trimmed sum returns a compensated pair.
+- :func:`gram` — the Krum Gram matrix of the row-0-centered client stack,
+  one fp32 MXU matmul per tile row, summed per grid step; the host adds
+  the per-step partials in fp64.
+- :func:`scatter_wsum` — the 0xF5 sparse fold: the device dequantizes
+  ``f32(q * scale)``, the host multiplies by the fp64 weight and scatters.
 
-Inputs arrive as already-stacked host arrays (see
-``FlatParams.tile_source`` / ``QuantParams.tile_source`` — the chunk->tile
-adapters): ``data`` is (clients, N) in the wire dtype (fp32/bf16/fp64 or
-int8), ``scales`` the per-``qchunk`` fp32 scales for int8 payloads, and
-``base`` the shared fp64 round-start vector for delta payloads.
+Layout.  A client's (N,) vector is padded to ``rows * lanes`` and viewed
+as (rows, lanes); the kernels take the stacked (C, rows, lanes) array in
+(C, tr, lanes) blocks, so the last two block dimensions obey the TPU's
+(8, 128) tiling rule (``tr`` is a multiple of 32, the int8 sublane packing,
+or all the rows).  ``lanes`` divides the int8 scale window ``qchunk``, so
+every row has one scale: the scales travel as a (C, rows, 1) array.
 
-Exactness contract (what `tests/test_agg_pallas.py` pins): every kernel
-reproduces the numpy reference **bitwise** (<=1 ULP guaranteed, 0
-observed) except the Gram matrix, whose matmul reduction order is
-hardware-defined.  Two implementation details make that possible and must
-not be "simplified" away:
+Delta payloads are never reconstructed here: a delta's shared round base
+is added by the caller at finalize (``sum w_k (d_k + b) == sum w_k d_k +
+W b``) — see ``StreamingWeightedSum``.
 
-- accumulation happens in a ``fori_loop`` whose trip count is a *runtime
-  scalar* (``n_ref``).  XLA:CPU compiles fused elementwise graphs with
-  LLVM fast-math, which contracts ``a*b + c`` into FMA and reassociates
-  unrolled add chains — up to ~1.5k fp64 ULP of drift under cancellation.
-  A while loop with a dynamic trip count cannot be unrolled, so the
-  multiply (materialized before the loop) and each add (one per
-  iteration) round exactly like the numpy fold.
-- int8 dequantization multiplies in fp32 and widens afterwards:
-  ``f64(f32(q * scale))`` is bitwise the numpy ``_dequant_q8`` chain
-  (the exact product fits fp64, then rounds through fp32 once).
-
-This container is CPU-only: kernels are validated with
-``pl.pallas_call(..., interpret=True)`` (fp64 under a scoped
-``jax.experimental.enable_x64``); the BlockSpecs/grids are the TPU
-configuration under test.  On real TPUs fp64 VPU throughput is emulated —
-:func:`weighted_sum` therefore offers ``tile_dtype="float32"`` (fp32
-tiles + fp64 carry: the decode/scale tile math at fp32 VPU rate, only
-the accumulate widened), selected by the sharded streaming fold on TPU
-hosts; the interpret-mode fp64 path stays the bitwise cross-check oracle.
+Error bound against the numpy fp64 fold: ``docs/INVARIANTS.md`` §1.  On a
+TPU host the kernels compile; everywhere else they run the same bodies in
+interpret mode (:mod:`repro.kernels.platform`).
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import math
+from typing import Optional, Tuple
 
 import numpy as np
 
 import jax
-import jax.experimental
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import interpret_mode
 
 DEFAULT_QCHUNK = 1024
-#: auto block sizing keeps the 1-D grid at most this long — interpret mode
-#: replays the kernel body once per grid step, so a 50M-element buffer
-#: must not become thousands of steps (on TPU the same bound keeps the
-#: per-step DMA large enough to hide latency)
-_MAX_GRID = 64
-_MIN_BLOCK = 8192
-_MAX_BLOCK = 1 << 21
+#: widest tile row; an int8 payload's row width divides its scale window
+LANES = 1024
+#: VMEM budget of one grid step's client stack, as fp32
+_TILE_BYTES = 1 << 20
+#: ... and of one fp32 (rows, lanes) tile: the kernels hold ~10 such
+#: temporaries next to the double-buffered operands, in 16 MiB of VMEM
+_ACC_TILE_BYTES = 1 << 19
+#: int8 packs (32, 128) tiles: partial blocks stay a multiple of this
+_ROW_ALIGN = 32
+#: Krum unrolls one matmul per tile row
+_GRAM_ROWS = 32
+#: clears the low 12 mantissa bits: x -> its 12-bit high half
+_HI_MASK = np.int32(-4096)
+#: per-client error of :func:`weighted_sum` against the fp64 fold, as a
+#: multiple of ``sum_c |w_c x_c|`` (``docs/INVARIANTS.md`` §1)
+FOLD_REL_ERR = 2.0 ** -42
+
+#: (lanes, rows per block, padded rows) — one fold's tiling
+Geometry = Tuple[int, int, int]
 
 
-def choose_block(n: int, qchunk: int = 1) -> int:
-    """Column-block size: a multiple of ``qchunk`` (int8 scale windows
-    never straddle blocks) with at most ``_MAX_GRID`` grid steps."""
-    blk = -(-_MIN_BLOCK // qchunk) * qchunk
-    while blk * _MAX_GRID < n and blk < _MAX_BLOCK:
-        blk *= 2
-    return blk
+def lanes_for(qchunk: Optional[int]) -> Optional[int]:
+    """Row width for a payload: ``LANES`` for float payloads; for int8 a
+    lane-aligned divisor of the scale window (one scale per row), or
+    ``None`` when the window is not a multiple of 128."""
+    if not qchunk:
+        return LANES
+    lanes = math.gcd(int(qchunk), LANES)
+    return lanes if lanes % 128 == 0 else None
 
 
-def _pad_cols(a: np.ndarray, total: int, fill=0) -> np.ndarray:
-    """Zero/fill-pad the last axis out to ``total`` columns."""
-    if a.shape[-1] == total:
-        return a
-    out = np.full(a.shape[:-1] + (total,), fill, a.dtype)
-    out[..., : a.shape[-1]] = a
-    return out
+def geometry(n: int, clients: int = 1, qchunk: Optional[int] = None,
+             block: Optional[int] = None, max_rows: int = 0) -> Geometry:
+    """Tiling of an (n,)-element fold over ``clients`` rows.  ``block``
+    (elements per grid step) is rounded to the tiling rule."""
+    lanes = lanes_for(qchunk)
+    if lanes is None:
+        raise ValueError(f"int8 scale window {qchunk} is not a multiple "
+                         f"of 128 lanes")
+    rows = max(-(-n // lanes), 1)
+    tr = min(block // lanes if block
+             else _TILE_BYTES // (4 * lanes * max(clients, 1)),
+             _ACC_TILE_BYTES // (4 * lanes))
+    if max_rows:
+        tr = min(tr, max_rows)
+    tr = -(-max(tr, 1) // _ROW_ALIGN) * _ROW_ALIGN
+    if rows <= tr:
+        return lanes, rows, rows        # one block: full-dim, always legal
+    return lanes, tr, -(-rows // tr) * tr
 
 
-def _decode_tile(d_ref, s_ref, b_ref, *, qchunk: int,
-                 tile_dtype=jnp.float64) -> jnp.ndarray:
-    """The fused wire decode: (C, blk) wire-dtype tile -> ``tile_dtype``.
-
-    int8 payloads dequantize through fp32 (one rounding, matching the
-    numpy ``_dequant_q8`` chain bitwise); float payloads widen exactly.
-    A delta payload's shared round base is added in fp64 afterwards, like
-    ``QuantParams.f64_chunk``.
-
-    ``tile_dtype=float32`` is the TPU production scheme (fp32 tiles +
-    fp64 carry): fp64 VPU throughput is emulated on TPU, so the tile math
-    (decode, scale) runs at fp32 rate and only the per-element
-    accumulate widens to the fp64 carry.  It is NOT bitwise against the
-    fp64 path (each product carries one extra fp32 rounding) — the
-    interpret-mode fp64 path stays the cross-check oracle.
-    """
-    raw = d_ref[...]
-    if raw.dtype == jnp.int8:
-        c, blk = raw.shape
-        dq = (raw.astype(jnp.float32).reshape(c, blk // qchunk, qchunk)
-              * s_ref[...][:, :, None]).reshape(c, blk)
-        t = dq.astype(tile_dtype)
-    else:
-        t = raw.astype(tile_dtype)
-    if b_ref is not None:
-        t = t + b_ref[...][None, :]
-    return t
+def _as_rows(a: np.ndarray, geom: Geometry, fill=0) -> np.ndarray:
+    """Pad the last axis to ``rows * lanes`` and view it as (rows, lanes)."""
+    lanes, _, rows = geom
+    total = rows * lanes
+    if a.shape[-1] != total:
+        out = np.full(a.shape[:-1] + (total,), fill, a.dtype)
+        out[..., :a.shape[-1]] = a
+        a = out
+    return a.reshape(a.shape[:-1] + (rows, lanes))
 
 
-def _assemble(data: np.ndarray, *, lead: int,
-              scales: Optional[np.ndarray], qchunk: int,
-              base: Optional[np.ndarray], acc: Optional[np.ndarray],
-              block: Optional[int]):
-    """Shared grid assembly for all three kernels: pick the block, pad
-    every operand to a whole number of blocks, and build the (args,
-    in_specs) lists in the order :func:`_unpack` consumes them —
-    ``(lead scalar, [acc], data, [scales], [base])``.  Returns
-    ``(blk, total, args, specs)``; callers append their tail operands.
-    """
-    c, n = data.shape
-    q8 = data.dtype == np.int8
-    blk = block or choose_block(n, qchunk if q8 else 1)
+def _data_rows(data: np.ndarray, geom: Geometry) -> np.ndarray:
+    if data.dtype == np.float64:
+        raise TypeError("fp64 payloads have no on-chip fold: the dispatch "
+                        "layer routes them to numpy (see agg_kernels)")
+    if data.dtype == np.float16:
+        data = data.astype(np.float32)  # exact; Mosaic has no f16 VPU path
+    return _as_rows(data, geom)
+
+
+def _scale_rows(scales: np.ndarray, qchunk: int, geom: Geometry):
+    """(C, nchunks) per-window scales -> one scale per tile row."""
+    lanes, _, rows = geom
+    per_row = np.repeat(np.asarray(scales, np.float32), qchunk // lanes,
+                        axis=-1)[..., :rows]
+    return _as_rows(per_row, (1, 0, geom[2]), fill=1)   # (C, rows, 1)
+
+
+def _split_weights(weights: np.ndarray) -> np.ndarray:
+    """fp64 weights -> (C, 4) fp32 words of <= 12 significant bits each,
+    largest first, summing to ``w`` within 2^-48 relative."""
+    w = np.asarray(weights, np.float64)
+    wh = w.astype(np.float32)
+    wl = (w - wh.astype(np.float64)).astype(np.float32)
+    words = []
+    for v in (wh, wl):
+        h = (v.view(np.int32) & _HI_MASK).view(np.float32)
+        words += [h, v - h]
+    return np.stack(words, axis=1)
+
+
+def _block_specs(geom: Geometry, clients: int, q8: bool):
+    lanes, tr, _ = geom
+    specs = [pl.BlockSpec((clients, tr, lanes), lambda i: (0, i, 0))]
     if q8:
-        blk = -(-blk // qchunk) * qchunk
-    total = -(-n // blk) * blk
-    args = [np.array([lead], np.int32)]
-    specs = [pl.BlockSpec((1,), lambda i: (0,))]
-    if acc is not None:
-        if not isinstance(acc, np.ndarray) and acc.shape[-1] == total:
-            # already-padded device array (streaming out_padded chain):
-            # pass through untouched so successive arrivals stay one
-            # async dispatch chain — no host sync, no copy
-            args.append(acc)
-        else:
-            args.append(_pad_cols(np.asarray(acc, np.float64), total))
-        specs.append(pl.BlockSpec((blk,), lambda i: (i,)))
-    args.append(_pad_cols(data, total))
-    specs.append(pl.BlockSpec((c, blk), lambda i: (0, i)))
-    if q8:
-        args.append(_pad_cols(np.asarray(scales, np.float32),
-                              total // qchunk, fill=1))
-        specs.append(pl.BlockSpec((c, blk // qchunk), lambda i: (0, i)))
-    if base is not None:
-        args.append(_pad_cols(np.asarray(base, np.float64), total))
-        specs.append(pl.BlockSpec((blk,), lambda i: (i,)))
-    return blk, total, args, specs
+        specs.append(pl.BlockSpec((clients, tr, 1), lambda i: (0, i, 0)))
+    return specs
 
 
-def _unpack(refs, *, q8: bool, has_base: bool, extra: int):
-    """(n_ref, [acc/extra...], data, [scales], [base], tail...)"""
-    it = iter(refs)
-    n_ref = next(it)
-    head = [next(it) for _ in range(extra)]
-    d_ref = next(it)
-    s_ref = next(it) if q8 else None
-    b_ref = next(it) if has_base else None
-    return n_ref, head, d_ref, s_ref, b_ref, list(it)
+def _decode(d_ref, s_ref, c: int):
+    """Client ``c``'s (tr, lanes) tile in fp32.  int8 dequantizes to
+    ``f32(q * scale)`` — bitwise the numpy ``_dequant_q8`` chain — as the
+    one rounded sum of two exact products (7-bit ``q`` times the 12-bit
+    halves of the scale), which no FMA contraction can alter."""
+    x = d_ref[c].astype(jnp.float32)
+    if s_ref is not None:
+        sh, sl = _split(s_ref[c])
+        x = x * sh + x * sl
+    return x
+
+
+# ---------------------------------------------------------------------------
+# error-free transformations (fp32)
+# ---------------------------------------------------------------------------
+def _split(x):
+    """x == hi + lo exactly, each with <= 12 significant bits.  A bit mask
+    instead of Veltkamp's ``x * 4097`` split, which FMA contraction breaks."""
+    xi = jax.lax.bitcast_convert_type(x, jnp.int32)
+    hi = jax.lax.bitcast_convert_type(xi & _HI_MASK, jnp.float32)
+    return hi, x - hi
+
+
+def _two_sum(a, b):
+    s = a + b
+    bb = s - a
+    return s, (a - (s - bb)) + (b - bb)
 
 
 # ---------------------------------------------------------------------------
 # fused weighted sum (FedAvg / streaming fold)
 # ---------------------------------------------------------------------------
-def _wsum_kernel(*refs, q8: bool, has_base: bool, has_acc: bool,
-                 qchunk: int, tile_dtype):
-    n_ref, head, d_ref, s_ref, b_ref, (w_ref, o_ref) = _unpack(
-        refs, q8=q8, has_base=has_base, extra=1 if has_acc else 0)
-    t = _decode_tile(d_ref, s_ref, b_ref, qchunk=qchunk,
-                     tile_dtype=tile_dtype)
-    # fp32 tiles: weights cast down so the scale multiply runs at VPU
-    # rate; the fp64-dtype cast below is the identity and preserves the
-    # bitwise contract of the default path
-    t = t * w_ref[...].astype(t.dtype)[:, None]
-
-    def body(c, a):
-        row = jax.lax.dynamic_index_in_dim(t, c, 0, keepdims=False)
-        return a + row.astype(jnp.float64)     # the fp64 carry
-
-    if has_acc:
-        init, lo = head[0][...], 0
+def _wsum_kernel(w_ref, *refs, clients: int, q8: bool, has_acc: bool):
+    it = iter(refs)
+    acc = (next(it), next(it)) if has_acc else None
+    d_ref = next(it)
+    s_ref = next(it) if q8 else None
+    oh_ref, ol_ref = next(it), next(it)
+    if acc is not None:
+        hi, lo = acc[0][...], acc[1][...]
     else:
-        init, lo = t[0].astype(jnp.float64), 1
-    # n_ref (a runtime scalar) keeps the loop a genuine while loop — see
-    # the module docstring for why unrolling would break bitwise parity;
-    # the det-fori-trip rule (docs/INVARIANTS.md) rejects any rewrite
-    # that makes this bound constant-foldable
-    o_ref[...] = jax.lax.fori_loop(lo, n_ref[0], body, init)
+        hi = jnp.zeros(oh_ref.shape, jnp.float32)
+        lo = jnp.zeros(oh_ref.shape, jnp.float32)
+    # static loop: clients fold in row order, each exactly once
+    for c in range(clients):
+        xh, xl = _split(_decode(d_ref, s_ref, c))
+        w0, w1, w2, w3 = (w_ref[c, k] for k in range(4))
+        # the three leading partial products go through TwoSum; the rest
+        # are below 2^-23 of the product and only round in the low word
+        hi, e1 = _two_sum(hi, xh * w0)
+        hi, e2 = _two_sum(hi, xh * w1)
+        hi, e3 = _two_sum(hi, xl * w0)
+        tail = (xl * w1 + xh * w2) + (xl * w2 + (xh * w3 + xl * w3))
+        hi, lo = _two_sum(hi, lo + ((e1 + e2) + (e3 + tail)))
+    oh_ref[...] = hi
+    ol_ref[...] = lo
+
+
+@functools.lru_cache(maxsize=None)
+def wsum_fn(geom: Geometry, clients: int, q8: bool, has_acc: bool,
+            interpret: bool):
+    """The jitted fused-fold kernel for one tiling (compiled once per
+    geometry).  Operands: the (C, 4) weight words, the ``(hi, lo)``
+    accumulator pair (``has_acc``), the (C, rows, lanes) data and, for
+    int8, the (C, rows, 1) scales."""
+    lanes, tr, rows = geom
+    tile = pl.BlockSpec((tr, lanes), lambda i: (i, 0))
+    specs = [pl.BlockSpec(memory_space=pltpu.SMEM)]
+    if has_acc:
+        specs += [tile, tile]
+    specs += _block_specs(geom, clients, q8)
+    return jax.jit(pl.pallas_call(
+        functools.partial(_wsum_kernel, clients=clients, q8=q8,
+                          has_acc=has_acc),
+        grid=(rows // tr,), in_specs=specs, out_specs=[tile, tile],
+        out_shape=[jax.ShapeDtypeStruct((rows, lanes), jnp.float32)] * 2,
+        interpret=interpret, name="agg_weighted_sum"))
 
 
 def weighted_sum(data: np.ndarray, weights: np.ndarray, *,
                  scales: Optional[np.ndarray] = None,
                  qchunk: int = DEFAULT_QCHUNK,
-                 base: Optional[np.ndarray] = None,
-                 acc: Optional[np.ndarray] = None,
+                 acc=None,
                  block: Optional[int] = None,
-                 interpret: bool = True,
-                 out_padded: bool = False,
-                 tile_dtype: str = "float64") -> np.ndarray:
+                 out_padded: bool = False):
     """``(acc +) sum_c weights[c] * decode(data[c])`` as one fused pass.
 
-    ``data``: (C, N) fp32/fp64/bf16 or int8 (with ``scales`` (C, S)).
-    ``base``: shared (N,) fp64 round-start vector for delta payloads.
-    ``acc``: (N,) fp64 running accumulator (the streaming arrival-order
-    fold); when given, all C rows fold *into* it.  Returns (N,) fp64.
-
-    ``out_padded=True`` returns the block-padded device array itself
-    (length a multiple of the block size) instead of a sliced host copy;
-    feeding it back as ``acc`` under the same geometry skips the
-    per-arrival pad + slice + host round-trip entirely, so successive
-    streaming arrivals form one asynchronous dispatch chain (decode of
-    arrival k+1 overlaps the device fold of arrival k).
-
-    ``tile_dtype="float32"`` runs the decode/scale tile math in fp32 with
-    an fp64 accumulate (the TPU production scheme — see `_decode_tile`);
-    it requires ``base=None`` and relaxes the bitwise contract to a
-    relative tolerance.
+    ``data``: (C, N) fp32/bf16/fp16 or int8 (with ``scales`` (C, S)).
+    ``acc``: the running accumulator — an (N,) fp64 host vector, or the
+    ``(hi, lo)`` device pair a previous ``out_padded=True`` call returned
+    under the same :func:`geometry` (so successive arrivals form one
+    asynchronous dispatch chain with no host round-trip).  Returns the
+    (N,) fp64 host vector, or with ``out_padded=True`` the padded device
+    pair (read it with :func:`pair_to_host`).
     """
-    if tile_dtype not in ("float64", "float32"):
-        raise ValueError(f"tile_dtype {tile_dtype!r}")
-    if tile_dtype == "float32" and base is not None:
-        raise ValueError("tile_dtype='float32' requires base=None "
-                         "(defer the delta base to finalize)")
     c, n = data.shape
     if n == 0:
         return np.zeros(0, np.float64) if acc is None else np.asarray(acc)
-    blk, total, args, specs = _assemble(
-        data, lead=c, scales=scales, qchunk=qchunk, base=base, acc=acc,
-        block=block)
-    args.append(np.asarray(weights, np.float64))
-    specs.append(pl.BlockSpec((c,), lambda i: (0,)))
-
-    kern = functools.partial(_wsum_kernel, q8=data.dtype == np.int8,
-                             has_base=base is not None,
-                             has_acc=acc is not None, qchunk=qchunk,
-                             tile_dtype=np.dtype(tile_dtype))
-    with jax.experimental.enable_x64():
-        out = pl.pallas_call(
-            kern, grid=(total // blk,), in_specs=specs,
-            out_specs=pl.BlockSpec((blk,), lambda i: (i,)),
-            out_shape=jax.ShapeDtypeStruct((total,), jnp.float64),
-            interpret=interpret,
-        )(*args)
-        if out_padded:
-            return out                  # padded device array, no sync
-        return np.array(out[:n])        # writable copy
-
-
-# ---------------------------------------------------------------------------
-# stacked-tile sort reductions (median / trimmed mean)
-# ---------------------------------------------------------------------------
-def _sort_kernel(*refs, q8: bool, has_base: bool, qchunk: int,
-                 kind: str, trim_k: int):
-    n_ref, _, d_ref, s_ref, b_ref, (o_ref,) = _unpack(
-        refs, q8=q8, has_base=has_base, extra=0)
-    t = _decode_tile(d_ref, s_ref, b_ref, qchunk=qchunk)
-    t = jnp.sort(t, axis=0)
-    c = t.shape[0]
-    if kind == "median":
-        if c % 2:
-            o_ref[...] = t[c // 2]
+    q8 = data.dtype == np.int8
+    geom = geometry(n, c, qchunk if q8 else None, block)
+    args = [_split_weights(weights)]
+    if acc is not None:
+        if isinstance(acc, tuple):
+            args += list(acc)
         else:
-            o_ref[...] = (t[c // 2 - 1] + t[c // 2]) / 2.0
+            a = np.asarray(acc, np.float64)
+            hi = a.astype(np.float32)
+            lo = (a - hi.astype(np.float64)).astype(np.float32)
+            args += [_as_rows(hi, geom), _as_rows(lo, geom)]
+    args.append(_data_rows(data, geom))
+    if q8:
+        args.append(_scale_rows(scales, qchunk, geom))
+    out = wsum_fn(geom, c, q8, acc is not None, interpret_mode())(*args)
+    if out_padded:
+        return tuple(out)               # padded device pair, no sync
+    return pair_to_host(out, n)
+
+
+def pair_to_host(pair, n: int) -> np.ndarray:
+    """The fp64 value ``hi + lo`` of a compensated accumulator (exact:
+    both words widen exactly and their sum fits fp64)."""
+    hi, lo = (np.asarray(a).reshape(-1)[:n] for a in pair)
+    return hi.astype(np.float64) + lo.astype(np.float64)
+
+
+# ---------------------------------------------------------------------------
+# sorting-network reductions (median / trimmed sum)
+# ---------------------------------------------------------------------------
+@functools.lru_cache(maxsize=None)
+def sort_pairs(n: int) -> Tuple[Tuple[int, int], ...]:
+    """Comparators of Batcher's odd-even merge sort for ``n`` inputs (the
+    power-of-two network with every comparator that touches a padding
+    row dropped — equivalent to padding with +inf)."""
+    out = []
+    p = 1
+    while p < n:
+        k = p
+        while k >= 1:
+            for j in range(k % p, n - k, 2 * k):
+                for i in range(min(k, n - j - k)):
+                    if (i + j) // (2 * p) == (i + j + k) // (2 * p):
+                        out.append((i + j, i + j + k))
+            k //= 2
+        p *= 2
+    return tuple(out)
+
+
+def _sort_kernel(*refs, clients: int, q8: bool, kind: str, trim_k: int):
+    d_ref = refs[0]
+    s_ref = refs[1] if q8 else None
+    o_ref = refs[-1]
+    t = [_decode(d_ref, s_ref, c) for c in range(clients)]
+    for a, b in sort_pairs(clients):
+        t[a], t[b] = jnp.minimum(t[a], t[b]), jnp.maximum(t[a], t[b])
+    if kind == "median":
+        o_ref[0] = t[(clients - 1) // 2]
+        o_ref[1] = t[clients // 2]
         return
+    hi, lo = t[trim_k], jnp.zeros_like(t[trim_k])
+    for r in range(trim_k + 1, clients - trim_k):
+        s, e = _two_sum(hi, t[r])
+        lo = lo + e
+        hi = s
+    o_ref[0], o_ref[1] = _two_sum(hi, lo)
 
-    def body(r, a):
-        return a + jax.lax.dynamic_index_in_dim(t, r, 0, keepdims=False)
 
-    # trimmed SUM of sorted rows [trim_k, n_ref[0]); the host divides by
-    # the row count so the mean's final divide is numpy's own
-    o_ref[...] = jax.lax.fori_loop(trim_k + 1, n_ref[0], body, t[trim_k])
+@functools.lru_cache(maxsize=None)
+def sort_fn(geom: Geometry, clients: int, q8: bool, kind: str, trim_k: int,
+            interpret: bool):
+    """The jitted sorting-network kernel for one tiling."""
+    lanes, tr, rows = geom
+    return jax.jit(pl.pallas_call(
+        functools.partial(_sort_kernel, clients=clients, q8=q8, kind=kind,
+                          trim_k=trim_k),
+        grid=(rows // tr,), in_specs=_block_specs(geom, clients, q8),
+        out_specs=pl.BlockSpec((2, tr, lanes), lambda i: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((2, rows, lanes), jnp.float32),
+        interpret=interpret, name=f"agg_sort_{kind}"))
 
 
 def sort_reduce(data: np.ndarray, *, kind: str = "median", trim_k: int = 0,
                 scales: Optional[np.ndarray] = None,
                 qchunk: int = DEFAULT_QCHUNK,
                 base: Optional[np.ndarray] = None,
-                block: Optional[int] = None,
-                interpret: bool = True) -> np.ndarray:
-    """Coordinate-wise sorted reduction over the (C, N) stack.
+                block: Optional[int] = None) -> np.ndarray:
+    """Coordinate-wise sorted reduction over the (C, N) stack -> (N,) fp64.
 
     ``kind="median"`` returns the per-coordinate median;
-    ``kind="trim_sum"`` returns the per-coordinate SUM of the sorted rows
-    ``[trim_k, C - trim_k)`` (the caller divides — see `_sort_kernel`).
+    ``kind="trim_sum"`` the per-coordinate SUM of the sorted rows
+    ``[trim_k, C - trim_k)`` (the caller divides, as numpy's mean does).
+    ``base`` (a shared delta base, fp64) is added on the host: sorting is
+    shift-invariant, so the device sorts the deltas alone.
     """
-    assert kind in ("median", "trim_sum"), kind
+    if kind not in ("median", "trim_sum"):
+        raise ValueError(f"kind {kind!r}")
     c, n = data.shape
     if n == 0:
         return np.zeros(0, np.float64)
-    blk, total, args, specs = _assemble(
-        data, lead=c - trim_k, scales=scales, qchunk=qchunk, base=base,
-        acc=None, block=block)
-
-    kern = functools.partial(_sort_kernel, q8=data.dtype == np.int8,
-                             has_base=base is not None,
-                             qchunk=qchunk, kind=kind, trim_k=trim_k)
-    with jax.experimental.enable_x64():
-        out = pl.pallas_call(
-            kern, grid=(total // blk,), in_specs=specs,
-            out_specs=pl.BlockSpec((blk,), lambda i: (i,)),
-            out_shape=jax.ShapeDtypeStruct((total,), jnp.float64),
-            interpret=interpret,
-        )(*args)
-        return np.array(out[:n])        # writable copy
+    q8 = data.dtype == np.int8
+    geom = geometry(n, c, qchunk if q8 else None, block)
+    args = [_data_rows(data, geom)]
+    if q8:
+        args.append(_scale_rows(scales, qchunk, geom))
+    out = sort_fn(geom, c, q8, kind, trim_k, interpret_mode())(*args)
+    out = np.asarray(out).reshape(2, -1)[:, :n].astype(np.float64)
+    if kind == "trim_sum":
+        vec = out[0] + out[1]
+        if base is not None:
+            vec += (c - 2 * trim_k) * np.asarray(base, np.float64)
+        return vec
+    lo, hi = out
+    if base is not None:
+        b = np.asarray(base, np.float64)
+        lo, hi = lo + b, hi + b
+    # numpy's median of an even count: (lo + hi) / 2, in fp64
+    return lo if c % 2 else (lo + hi) / 2.0
 
 
 # ---------------------------------------------------------------------------
 # Krum Gram matrix
 # ---------------------------------------------------------------------------
-def _gram_kernel(*refs, q8: bool, has_base: bool, qchunk: int):
-    _, _, d_ref, s_ref, b_ref, (o_ref,) = _unpack(
-        refs, q8=q8, has_base=has_base, extra=0)
-    t = _decode_tile(d_ref, s_ref, b_ref, qchunk=qchunk)
-    # center on the first row: pairwise distances are translation
-    # invariant, and removing the common component keeps the
-    # ||a||^2+||b||^2-2<a,b> expansion from cancelling catastrophically
-    t = t - t[0]
-    g = jnp.dot(t, t.T, preferred_element_type=jnp.float64)
-    i = pl.program_id(0)
+def _gram_kernel(*refs, clients: int, q8: bool, rows: int):
+    d_ref = refs[0]
+    s_ref = refs[1] if q8 else None
+    o_ref = refs[-1]
+    g = jnp.zeros((clients, clients), jnp.float32)
+    for r in range(rows):
+        t = d_ref[:, pl.ds(r, 1), :].astype(jnp.float32) \
+            .reshape(clients, -1)
+        if s_ref is not None:
+            t = t * s_ref[:, pl.ds(r, 1), :].reshape(clients, 1)
+        # center on the first row: pairwise distances are translation
+        # invariant, and removing the common component keeps the
+        # ||a||^2+||b||^2-2<a,b> expansion from cancelling catastrophically
+        t = t - t[0:1, :]
+        g = g + jax.lax.dot_general(
+            t, t, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+    o_ref[0] = g
 
-    @pl.when(i == 0)
-    def _():
-        o_ref[...] = g
 
-    @pl.when(i > 0)
-    def _():
-        o_ref[...] += g
+@functools.lru_cache(maxsize=None)
+def gram_fn(geom: Geometry, clients: int, q8: bool, interpret: bool):
+    """The jitted Gram kernel for one tiling."""
+    lanes, tr, rows = geom
+    return jax.jit(pl.pallas_call(
+        functools.partial(_gram_kernel, clients=clients, q8=q8, rows=tr),
+        grid=(rows // tr,), in_specs=_block_specs(geom, clients, q8),
+        out_specs=pl.BlockSpec((1, clients, clients), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows // tr, clients, clients),
+                                       jnp.float32),
+        interpret=interpret, name="agg_gram"))
 
 
 def gram(data: np.ndarray, *,
          scales: Optional[np.ndarray] = None,
          qchunk: int = DEFAULT_QCHUNK,
-         base: Optional[np.ndarray] = None,
-         block: Optional[int] = None,
-         interpret: bool = True) -> np.ndarray:
-    """(C, C) fp64 Gram matrix of the row-0-centered client stack,
-    accumulated one column block per grid step (the Krum distance
-    kernel's MXU half; the host expands distances and scores)."""
+         block: Optional[int] = None) -> np.ndarray:
+    """(C, C) fp64 Gram matrix of the row-0-centered client stack.  A
+    shared delta base cancels in the centering, so deltas need none.
+    Each grid step returns its fp32 partial; the host sums them in fp64."""
     c, n = data.shape
     if n == 0:
         return np.zeros((c, c), np.float64)
-    blk, total, args, specs = _assemble(
-        data, lead=c, scales=scales, qchunk=qchunk, base=base, acc=None,
-        block=block)
-
-    kern = functools.partial(_gram_kernel, q8=data.dtype == np.int8,
-                             has_base=base is not None, qchunk=qchunk)
-    with jax.experimental.enable_x64():
-        out = pl.pallas_call(
-            kern, grid=(total // blk,), in_specs=specs,
-            out_specs=pl.BlockSpec((c, c), lambda i: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((c, c), jnp.float64),
-            interpret=interpret,
-        )(*args)
-        return np.array(out)            # writable copy
+    q8 = data.dtype == np.int8
+    geom = geometry(n, c, qchunk if q8 else None, block,
+                    max_rows=_GRAM_ROWS)
+    args = [_data_rows(data, geom)]
+    if q8:
+        args.append(_scale_rows(scales, qchunk, geom))
+    out = gram_fn(geom, c, q8, interpret_mode())(*args)
+    return np.asarray(out).astype(np.float64).sum(axis=0)
 
 
 # ---------------------------------------------------------------------------
 # structured-sparse scatter fold (0xF5 payloads)
 # ---------------------------------------------------------------------------
-@functools.partial(jax.jit, static_argnames=("q8",))
-def _sparse_contrib(vals, escales, w, *, q8: bool):
-    if q8:
-        # exact int8*fp32 product in fp64, then ONE fp32 rounding — the
-        # numpy ``_dequant_q8`` chain bitwise (module docstring)
-        t = vals.astype(jnp.float64) * escales.astype(jnp.float64)
-        r = t.astype(jnp.float32).astype(jnp.float64)
-    else:
-        r = vals.astype(jnp.float64)
-    return r * w
+@jax.jit
+def dequant_q8(vals, escales):
+    """``f32(q * scale)`` elementwise — bitwise the numpy ``_dequant_q8``
+    chain (one correctly rounded fp32 product)."""
+    return vals.astype(jnp.float32) * escales
 
 
 def _pad_pow2(a: np.ndarray) -> np.ndarray:
-    """Zero-pad to the next power of two so `_sparse_contrib` compiles
-    once per size class instead of once per span length."""
+    """Zero-pad to the next power of two so `dequant_q8` compiles once
+    per size class instead of once per span length."""
     n = a.size
     p = 1
     while p < n:
@@ -398,14 +460,11 @@ def scatter_wsum(acc: np.ndarray, dest, vals: np.ndarray, w: float, *,
                  qchunk: int = DEFAULT_QCHUNK, pos0: int = 0) -> None:
     """``acc[dest] += w * dequant(vals)`` — the 0xF5 sparse-delta fold.
 
-    Deliberately NOT a ``pl.pallas_call``: a data-dependent scatter has
-    no tile structure (the destination indices are runtime values, so
-    there is no BlockSpec that maps grid steps to disjoint output
-    blocks).  Instead the O(nnz) dequantize+scale chain runs as a jitted
-    XLA elementwise graph under scoped x64 — mirroring the numpy
-    ``_dequant_q8`` rounding chain bitwise — and the final unique-index
-    scatter-add happens on the host accumulator, where `+=` with unique
-    indices has no reduction-order ambiguity.
+    Not a ``pl.pallas_call``: a data-dependent scatter has no tile
+    structure.  The device dequantizes (fp32, :func:`dequant_q8`); the
+    host multiplies by the fp64 weight and scatters into the fp64
+    accumulator, where ``+=`` with unique indices has no reduction-order
+    ambiguity — the same operations as the numpy fold, so bitwise equal.
 
     ``acc``: fp64 accumulator segment (mutated in place).  ``dest``: a
     slice or unique index array *relative to acc*.  ``vals``: packed
@@ -415,15 +474,11 @@ def scatter_wsum(acc: np.ndarray, dest, vals: np.ndarray, w: float, *,
     n = vals.size
     if n == 0:
         return
-    q8 = vals.dtype == np.int8
-    if q8:
+    if vals.dtype == np.int8:
         # per-element scale of the packed stream (host gather, O(nnz))
         esc = np.asarray(scales, np.float32)[
             (pos0 + np.arange(n, dtype=np.int64)) // qchunk]
-        esc = _pad_pow2(esc)
+        r = np.asarray(dequant_q8(_pad_pow2(vals), _pad_pow2(esc)))[:n]
     else:
-        esc = np.zeros(0, np.float32)
-    with jax.experimental.enable_x64():
-        contrib = _sparse_contrib(_pad_pow2(vals), esc,
-                                  jnp.float64(w), q8=q8)
-        acc[dest] += np.asarray(contrib)[:n]
+        r = vals
+    acc[dest] += r.astype(np.float64) * np.float64(w)

@@ -17,15 +17,15 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
+from repro.kernels.platform import interpret_mode
+
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -93,7 +93,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 512, block_kv: int = 512,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """q: (B,S,H,hd) pre-scaled; k/v: (B,S,KV,hd) -> (B,S,H,hd_v)."""
     B, S, H, hd = q.shape
     KV = k.shape[2]
@@ -141,8 +141,8 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q, 1), jnp.float32),      # running sum l
             pltpu.VMEM((block_q, hdv), jnp.float32),    # output accumulator
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(qf, kf, vf)
     return jnp.moveaxis(out[:, :S].reshape(B, H, S, hdv), 1, 2)

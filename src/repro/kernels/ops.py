@@ -11,25 +11,18 @@ from repro.kernels.rglru_scan import rglru_scan as _rglru
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
-                                             "block_kv", "interpret"))
+                                             "block_kv"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    block_q: int = 512, block_kv: int = 512,
-                    interpret: bool = True):
+                    block_q: int = 512, block_kv: int = 512):
     return _flash(q, k, v, causal=causal, window=window, block_q=block_q,
-                  block_kv=block_kv, interpret=interpret)
+                  block_kv=block_kv)
 
 
-@functools.partial(jax.jit, static_argnames=("quant_bits", "block",
-                                             "interpret"))
-def secagg_mask(x, masks, weight, *, quant_bits: int = 16, block: int = 4096,
-                interpret: bool = True):
-    return _secagg(x, masks, weight, quant_bits=quant_bits, block=block,
-                   interpret=interpret)
+@functools.partial(jax.jit, static_argnames=("quant_bits", "block"))
+def secagg_mask(x, masks, weight, *, quant_bits: int = 16, block: int = 4096):
+    return _secagg(x, masks, weight, quant_bits=quant_bits, block=block)
 
 
-@functools.partial(jax.jit, static_argnames=("block_s", "block_w",
-                                             "interpret"))
-def rglru_scan(a, b, h0, *, block_s: int = 256, block_w: int = 512,
-               interpret: bool = True):
-    return _rglru(a, b, h0, block_s=block_s, block_w=block_w,
-                  interpret=interpret)
+@functools.partial(jax.jit, static_argnames=("block_s", "block_w"))
+def rglru_scan(a, b, h0, *, block_s: int = 256, block_w: int = 512):
+    return _rglru(a, b, h0, block_s=block_s, block_w=block_w)

@@ -19,9 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
+from repro.kernels.platform import interpret_mode
 
 
 def _kernel(h0_ref, a_ref, b_ref, y_ref, hout_ref, h_ref, *,
@@ -48,8 +46,7 @@ def _kernel(h0_ref, a_ref, b_ref, y_ref, hout_ref, h_ref, *,
         hout_ref[0] = h
 
 
-def rglru_scan(a, b, h0, *, block_s: int = 256, block_w: int = 512,
-               interpret: bool = True):
+def rglru_scan(a, b, h0, *, block_s: int = 256, block_w: int = 512):
     B, S, W = a.shape
     block_s = min(block_s, S)
     while S % block_s:
@@ -76,8 +73,8 @@ def rglru_scan(a, b, h0, *, block_s: int = 256, block_w: int = 512,
             jax.ShapeDtypeStruct((B, W), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_w,), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(h0, a, b)
     return ys, hf

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import interpret_mode
+
 
 def _kernel(x_ref, m_ref, w_ref, o_ref, *, quant_bits: int):
     x = x_ref[...].astype(jnp.float32)
@@ -33,8 +35,7 @@ def _kernel(x_ref, m_ref, w_ref, o_ref, *, quant_bits: int):
     o_ref[...] = q + total
 
 
-def secagg_mask(x, masks, weight, *, quant_bits: int = 16, block: int = 4096,
-                interpret: bool = True):
+def secagg_mask(x, masks, weight, *, quant_bits: int = 16, block: int = 4096):
     """x: (N,) float32; masks: (P, N) int32; weight: scalar -> (N,) int32."""
     N = x.shape[0]
     P = masks.shape[0] if masks.size else 0
@@ -57,5 +58,5 @@ def secagg_mask(x, masks, weight, *, quant_bits: int = 16, block: int = 4096,
         ],
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((N,), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(x, masks, w)

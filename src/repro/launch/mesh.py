@@ -10,6 +10,7 @@ touches jax device state (the dry-run must set XLA_FLAGS first).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 # TPU v5e hardware constants (per chip) used by the roofline analysis.
 PEAK_FLOPS_BF16 = 197e12          # FLOP/s
@@ -20,12 +21,12 @@ ICI_BW = 50e9                     # bytes/s per link
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over however many devices exist (tests on CPU)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _mesh((data, model), ("data", "model"))
 
 
 def make_agg_mesh(shards: int):
@@ -38,4 +39,11 @@ def make_agg_mesh(shards: int):
     CPU CI the devices are simulated with
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N``.
     """
-    return jax.make_mesh((shards,), ("data",))
+    return _mesh((shards,), ("data",))
+
+
+def _mesh(shape, axes):
+    # Auto axes: the shardings in launch/shardings.py are GSPMD hints, and
+    # jax.make_mesh's default Explicit axes reject the fsdp embedding
+    # gather at trace time
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

@@ -2,7 +2,8 @@
 
 ``impl="xla"`` — einsum + masked softmax; lowers on every backend and is
 what the 512-device dry-run compiles.  ``impl="pallas"`` — the flash
-attention TPU kernel from ``repro.kernels`` (interpret-mode on CPU).
+attention TPU kernel from ``repro.kernels`` (compiled on a TPU, interpret
+mode elsewhere — ``repro.kernels.platform.interpret_mode``).
 Both satisfy the same contract and are cross-checked in tests.
 """
 from __future__ import annotations
@@ -39,8 +40,7 @@ def causal_attention(q, k, v, *, window: int = 0, impl: str = "xla",
     if impl == "pallas":
         from repro.kernels import ops
 
-        return ops.flash_attention(q, k, v, causal=causal, window=window,
-                                   interpret=True)
+        return ops.flash_attention(q, k, v, causal=causal, window=window)
     B, S, H, hd = q.shape
     KV = k.shape[2]
     g = H // KV

@@ -49,7 +49,7 @@ BAD_CASES = [
     (["determinism/fl/bad_set_iter.py"], {"det-set-iter"}),
     (["determinism/fl/bad_entropy.py"], {"det-entropy"}),
     (["determinism/kernels/bad_float_accum.py"], {"det-float-accum"}),
-    (["determinism/kernels/bad_fori.py"], {"det-fori-trip"}),
+    (["determinism/kernels/bad_split.py"], {"det-fma-split"}),
     (["aliasing/bad_frombuffer.py"], {"alias-writeable"}),
     (["aliasing/bad_mutation.py"], {"alias-mutation"}),
     (["codec/fl/flat.py", "codec/bad_literal.py"], {"codec-literal"}),
@@ -68,7 +68,7 @@ GOOD_CASES = [
     ["determinism/fl/good_set_iter.py"],
     ["determinism/fl/good_entropy.py"],
     ["determinism/kernels/good_float_accum.py"],
-    ["determinism/kernels/good_fori.py"],
+    ["determinism/kernels/good_split.py"],
     ["aliasing/good_frombuffer.py"],
     ["aliasing/good_mutation.py"],
     ["codec/fl/flat.py", "codec/good_literal.py"],

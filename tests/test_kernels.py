@@ -200,3 +200,41 @@ def test_pallas_path_matches_xla_path_in_model():
     b = attention_impl.causal_attention(q, k, v, impl="pallas")
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
                                atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# platform: interpret mode and the compile cache
+# ---------------------------------------------------------------------------
+def test_interpret_mode_follows_the_platform():
+    from repro.kernels import platform
+
+    assert platform.interpret_mode() is (jax.default_backend() != "tpu")
+    assert platform.interpret_mode(True) is True
+    assert platform.interpret_mode(False) is False
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir(monkeypatch, tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR, when set, is left to JAX; otherwise the
+    cache goes to one fixed, git-ignored directory in the checkout."""
+    import pathlib
+
+    from repro.kernels import platform
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        if from_env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert platform.enable_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir is None
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            path = pathlib.Path(platform.enable_compile_cache())
+            assert jax.config.jax_compilation_cache_dir == str(path)
+            root = pathlib.Path(__file__).resolve().parents[1]
+            assert path.parent == root
+            ignored = (root / ".gitignore").read_text().split()
+            assert f"{path.name}/" in ignored
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

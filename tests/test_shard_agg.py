@@ -180,14 +180,20 @@ def test_padded_acc_cached_across_homogeneous_arrivals():
 
 
 def test_padded_acc_retired_on_geometry_change():
-    """block=1536: q8 rounds the block up to the 1024 scale window
-    (-> 2048) while raw frames keep 1536, so interleaving the codecs
-    forces the retire + re-pad fallback on every switch — which must
-    stay invisible in the result."""
-    layout, quants = make_payloads("big_unaligned", "q8", 2, seed=33)
-    _, raws = make_payloads("big_unaligned", "flat", 1, seed=34)
+    """An int8 payload with a 256-element scale window tiles 256 lanes
+    wide while raw frames tile 1024, so interleaving the two forces the
+    retire + re-pad fallback on every switch — which must stay invisible
+    in the result."""
+    layout, raws = make_payloads("big_unaligned", "flat", 1, seed=34)
+    rng = np.random.default_rng(33)
+    quants = []
+    for _ in range(2):
+        q, sc = quantize_int8(
+            rng.normal(0, 1, layout.total_size).astype(np.float32),
+            qchunk=256)
+        quants.append(QuantParams(layout, "q8", q, sc, qchunk=256))
     arrivals = [(quants[0], 2.0), (raws[0], 3.0), (quants[1], 4.0)]
-    s = K.StreamingWeightedSum(layout, backend="pallas", block=1536)
+    s = K.StreamingWeightedSum(layout, backend="pallas")
     geoms = []
     for fp, w in arrivals:
         s.add(fp, w)

@@ -24,7 +24,7 @@ from repro.core.framing import (FT_BYE, FT_HELLO, FT_PING, FT_PONG, FT_REQ,
                                 split_data, unpack_unary)
 from repro.core.superlink import SuperLinkDriver, SuperNode
 from repro.core.transport import (TcpFleetConnection, TcpSuperLink,
-                                  run_supernode)
+                                  run_supernode, start_off_chip)
 from repro.fl import ClientApp, NumPyClient, ServerApp, ServerConfig
 from repro.fl.strategy import make_strategy
 from repro.runtime.reliable import RequestTimeout
@@ -508,8 +508,7 @@ def test_tcp_16proc_round_bitwise_vs_inproc(tmp_path):
                                          max_disconnected=10.0),
                              daemon=True)
                  for s in sites]
-        for p in procs:
-            p.start()
+        start_off_chip(procs)
         try:
             deadline = time.monotonic() + 300
             while len(link.node_ids()) < N_PROCS:
@@ -544,7 +543,7 @@ def test_tcp_kill9_client_mid_round_records_timeout():
                                          heartbeat_interval=0.2,
                                          max_disconnected=5.0),
                              daemon=True)
-        victim.start()
+        start_off_chip([victim])
         good = SuperNode("good", _det_app("good-0"),
                          TcpFleetConnection(host, port, "good"))
         good.start()
