@@ -1,0 +1,14 @@
+"""Client fit step: model FLOPs of the train steps over (their summed
+device time x peak bf16 FLOP/s), in percent.  A train step is one
+``train_step`` program event."""
+from readings import NS
+
+
+def read(ctx):
+    steps = ctx.device_events("modules", lambda e: "train_step" in e.name)
+    if not steps:
+        return None
+    per_step = ctx.fit_flops_per_round / (ctx.mix["sites"]
+                                          * ctx.mix["local_steps"])
+    busy = sum(e.end - e.start for e in steps) * NS
+    return 100.0 * per_step * len(steps) / (busy * ctx.peaks["bf16_flops"])
