@@ -63,27 +63,22 @@ def _example():
 # one chip
 # ---------------------------------------------------------------------------
 def _recording_fedavg():
-    """FedAvg that times each round (tasks sent -> new global model) and
-    keeps the last round's arrivals for the fold check."""
+    """FedAvg that keeps each round's client train losses and the last
+    round's arrivals and new model for the fold check."""
     from repro.fl import FedAvg
 
     class RecordingFedAvg(FedAvg):
-        def configure_fit(self, rnd, parameters, nodes):
-            self.t_sent = time.perf_counter()
-            return super().configure_fit(rnd, parameters, nodes)
-
         def fit_accumulator(self, rnd, current):
             acc = super().fit_accumulator(rnd, current)
             finalize = acc.finalize
 
-            def timed_finalize(failures):
+            def recording_finalize(failures):
                 self.arrivals = [(fp, w) for _, fp, w in sorted(
                     acc.pairs, key=lambda p: p[0])]
                 self.fit_losses.setdefault(rnd, []).extend(
                     float(r.metrics["train_loss"]) for r in self._fits)
                 self._fits = []
                 out = finalize(failures)
-                self.round_s[rnd] = time.perf_counter() - self.t_sent
                 self.round_out = out[0]
                 return out
 
@@ -93,12 +88,12 @@ def _recording_fedavg():
                 self._fits.append(res)
                 add(node, res)
 
-            acc.finalize = timed_finalize
+            acc.finalize = recording_finalize
             acc.add = add_and_keep_metrics
             return acc
 
     strat = RecordingFedAvg()
-    strat.round_s, strat.fit_losses, strat._fits = {}, {}, []
+    strat.fit_losses, strat._fits = {}, []
     strat.arrivals, strat.round_out = [], None
     return strat
 
@@ -202,8 +197,7 @@ def one_chip(scale: str = "full") -> None:
     t0 = time.perf_counter()
     hist = ex.run(scale, ROUNDS, LOCAL_STEPS, codec="q8", strategy=strat,
                   mesh=mesh)
-    log(f"q8: run wall {time.perf_counter() - t0!r} s; per-round wall "
-        f"(tasks sent -> new global model) {strat.round_s}")
+    log(f"q8: run wall {time.perf_counter() - t0!r} s")
     codecs = {r.metrics.get("wire_codec") for r in hist.rounds}
     if codecs != {"q8"}:
         fail(f"q8 run negotiated {codecs}")
@@ -227,8 +221,7 @@ def one_chip(scale: str = "full") -> None:
     t0 = time.perf_counter()
     hist = ex.run(scale, ROUNDS, LOCAL_STEPS, codec="sparse",
                   strategy=strat, mesh=mesh)
-    log(f"sparse: run wall {time.perf_counter() - t0!r} s; per-round "
-        f"wall {strat.round_s}")
+    log(f"sparse: run wall {time.perf_counter() - t0!r} s")
     codecs = {r.metrics.get("wire_codec") for r in hist.rounds}
     if codecs != {"sparse"}:
         fail(f"sparse run negotiated {codecs}")

@@ -47,11 +47,15 @@ from repro.fl.messages import (EvaluateRes, FitRes, TaskIns, TaskRes,
 from repro.fl.server import Driver
 from repro.fl.strategy import _flat_of
 from repro.runtime.reliable import RequestTimeout
+from repro.utils import tracing
 
 # Tombstones for in-flight tasks whose round already gave up on them are
 # pruned after this many seconds; a responsive-but-slow node clears its own
 # tombstone the moment its late result arrives (and is dropped).
 _TOMBSTONE_TTL = 120.0
+
+#: the fleet ``pull_task_ins`` response of an empty queue
+EMPTY_PULL = msgpack.packb({"id": "", "task": b""}, use_bin_type=True)
 
 
 class _Waiter:
@@ -82,9 +86,19 @@ class SuperLink:
         # every ``with self._lock`` block may wait/notify on it directly
         self._tasks_cv = threading.Condition(self._lock)
         self.stats = {"late_dropped": 0, "discarded_ins": 0}  # guarded-by: _results_cv
+        # while a trace is taken: when each queued TaskIns was pushed and
+        # each undelivered result landed (the spans' ``queued_s``)
+        self._pushed_at: Dict[str, float] = {}               # guarded-by: _lock
+        self._landed_at: Dict[str, float] = {}               # guarded-by: _results_cv
 
     # ------------------------------------------------------------ fleet API
     def fleet_unary(self, method: str, request: bytes) -> bytes:
+        with tracing.span("repro.superlink.serve", method=method) as s:
+            return self._fleet_unary(method, request, s)
+
+    def _fleet_unary(self, method: str, request: bytes, s) -> bytes:
+        """One fleet call inside its span ``s``, which gets a returned
+        task's ``queued_s``: how long it sat in its node queue."""
         if method == "register":
             node_id = request.decode()
             with self._lock:
@@ -97,7 +111,12 @@ class SuperLink:
             node_id = request.decode()
             with self._lock:
                 q = self._task_queues.setdefault(node_id, deque())
-                task_id, task = q.popleft() if q else ("", b"")
+                if not q:
+                    return EMPTY_PULL
+                task_id, task = q.popleft()
+                pushed = self._pushed_at.pop(task_id, None)
+            if s and pushed is not None:
+                tracing.annotate(s, queued_s=time.perf_counter() - pushed)
             return msgpack.packb({"id": task_id, "task": task},
                                  use_bin_type=True)
         if method == "push_task_res":
@@ -122,7 +141,9 @@ class SuperLink:
                 if remaining <= 0:
                     return "", b""
                 self._tasks_cv.wait(remaining)
-            return q.popleft()
+            task_id, task = q.popleft()
+            self._pushed_at.pop(task_id, None)
+            return task_id, task
 
     def push_task_result(self, task_id: str, res: bytes) -> bool:
         """Complete ``task_id`` with ``res``; False if the round already
@@ -143,6 +164,8 @@ class SuperLink:
                     w.ready.append((task_id, res))   # O(1) routing
                 else:
                     self._results[task_id] = res
+                if tracing.enabled():
+                    self._landed_at[task_id] = time.perf_counter()
                 self._results_cv.notify_all()
         if dropped:
             self._result_released(task_id)
@@ -176,6 +199,8 @@ class SuperLink:
         with self._lock:
             self._task_queues.setdefault(node_id, deque()).append(
                 (task_id, task))
+            if tracing.enabled():
+                self._pushed_at[task_id] = time.perf_counter()
             self._tasks_cv.notify_all()     # wake long-poll pulls
         return task_id
 
@@ -212,6 +237,7 @@ class SuperLink:
         ``(task_id, res_bytes)`` or ``None``.  Full-duration CV wait —
         no periodic polling, no per-wakeup id scan."""
         got: Optional[Tuple[str, bytes]] = None
+        landed = None
         with self._results_cv:
             while not w.ready:
                 remaining = deadline - time.monotonic()
@@ -220,9 +246,14 @@ class SuperLink:
                 self._results_cv.wait(remaining)
             if w.ready:
                 got = w.ready.popleft()
+                landed = self._landed_at.pop(got[0], None)
         if got is not None:
-            # outside the CV: the hook may take transport locks / do I/O
-            self._result_released(got[0])
+            with tracing.span("repro.superlink.deliver") as s:
+                if s and landed is not None:
+                    tracing.annotate(
+                        s, queued_s=time.perf_counter() - landed)
+                # outside the CV: the hook may take transport locks / do I/O
+                self._result_released(got[0])
         return got
 
     def release_waiter(self, w: _Waiter,
@@ -278,12 +309,15 @@ class SuperLink:
                     kept = deque(e for e in q if e[0] not in ids)
                     undelivered.update(tid for tid, _ in q if tid in ids)
                     self._task_queues[node] = kept
+            for tid in undelivered:
+                self._pushed_at.pop(tid, None)
         now = time.monotonic()
         dropped: List[str] = []
         with self._results_cv:
             self.stats["discarded_ins"] += len(undelivered)
             for tid in ids:
                 self._waiters.pop(tid, None)     # stop routing to cursors
+                self._landed_at.pop(tid, None)
                 if self._results.pop(tid, None) is not None:
                     dropped.append(tid)          # landed but unwanted: done
                     continue

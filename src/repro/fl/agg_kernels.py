@@ -74,6 +74,7 @@ import numpy as np
 
 from repro.fl.flat import FlatParams, Layout, memo_token, np_dtype
 from repro.kernels.platform import on_tpu
+from repro.utils import tracing
 
 # 16K elements: chunk fp64 accumulator + scratch = 256 KiB, L2-resident.
 # QCHUNK (int8 scale window) divides CHUNK, so quantized reads stay aligned.
@@ -263,7 +264,11 @@ def weighted_mean(pairs: Sequence[Tuple[FlatParams, float]],
         return out
     vec = _weighted_mean_pallas(pairs, backend, block)
     if vec is not None:
-        return _vec_to_flat(vec, layout)
+        with tracing.span("repro.fold.unstage") as s:
+            out = _vec_to_flat(vec, layout)
+            if s:
+                tracing.annotate(s, nbytes=vec.nbytes, clients=len(pairs))
+        return out
     uniform = layout.uniform_dtype in _FLOATS
     ovec = out.math_view() if uniform else np.empty(layout.total_size,
                                                     np.float64)
@@ -300,18 +305,32 @@ def _weighted_mean_pallas(pairs, backend: Optional[str],
                           block: Optional[int]) -> Optional[np.ndarray]:
     if resolve_backend(backend) != "pallas":
         return None
-    stack = _tile_stack([fp for fp, _ in pairs])
+    with tracing.span("repro.fold.stage") as s:
+        stack = _tile_stack([fp for fp, _ in pairs])
+        if s and stack is not None:
+            tracing.annotate(s, clients=len(pairs), nbytes=sum(
+                a.nbytes for a in (stack["data"], stack["scales"],
+                                   stack["base"]) if a is not None))
     if stack is None:
         return None
     from repro.kernels import agg_reduce
 
     scaled = _scaled_weights(pairs)
-    vec = agg_reduce.weighted_sum(
-        stack["data"], np.array(scaled, np.float64),
-        scales=stack["scales"], qchunk=stack["qchunk"], block=block)
+    with tracing.span("repro.fold.kernel") as s:
+        vec = agg_reduce.weighted_sum(
+            stack["data"], np.array(scaled, np.float64),
+            scales=stack["scales"], qchunk=stack["qchunk"], block=block)
+        if s:
+            tracing.annotate(s, clients=len(pairs), nbytes=sum(
+                a.nbytes for a in (stack["data"], stack["scales"])
+                if a is not None))
     if stack["base"] is not None:
-        # deferred delta base: sum s_i (d_i + b) == sum s_i d_i + S b
-        vec += np.float64(sum(scaled)) * stack["base"]
+        with tracing.span("repro.fold.unstage") as s:
+            # deferred delta base: sum s_i (d_i + b) == sum s_i d_i + S b
+            vec += np.float64(sum(scaled)) * stack["base"]
+            if s:
+                tracing.annotate(s, clients=len(pairs),
+                                 nbytes=stack["base"].nbytes)
     return vec
 
 
@@ -722,11 +741,20 @@ class StreamingWeightedSum:
     def finalize(self) -> FlatParams:
         if self.shards:
             return self._finalize_sharded()
-        acc = self._acc_vec()
-        acc *= np.float64(1.0 / self.total_w)
-        self._apply_deferred(acc, self.total_w)
-        out = FlatParams.zeros(self.layout)
-        _scatter_leaves(acc, self.layout, out)
+        if self._acc_padded is not None:
+            with tracing.span("repro.fold.kernel") as s:
+                self._acc_vec()     # waits for the device fold, copies back
+                if s:
+                    tracing.annotate(s, clients=self.count,
+                                     nbytes=self._acc.nbytes)
+        with tracing.span("repro.fold.unstage") as s:
+            acc = self._acc_vec()
+            acc *= np.float64(1.0 / self.total_w)
+            self._apply_deferred(acc, self.total_w)
+            out = FlatParams.zeros(self.layout)
+            _scatter_leaves(acc, self.layout, out)
+            if s:
+                tracing.annotate(s, clients=self.count, nbytes=acc.nbytes)
         return out
 
     def per_shard_acc_bytes(self) -> int:
@@ -767,10 +795,15 @@ class StreamingWeightedSum:
         if self._pad_geom is not None and self._pad_geom != geom:
             self._acc_vec()         # mixed arrival: retire, re-pad below
         acc = self._acc_padded if self._pad_geom == geom else self._acc
-        self._acc_padded = agg_reduce.weighted_sum(
-            src.data[None, :], np.array([w], np.float64),
-            scales=None if src.scales is None else src.scales[None, :],
-            qchunk=src.qchunk, acc=acc, block=self._block, out_padded=True)
+        with tracing.span("repro.fold.kernel") as s:
+            self._acc_padded = agg_reduce.weighted_sum(
+                src.data[None, :], np.array([w], np.float64),
+                scales=None if src.scales is None else src.scales[None, :],
+                qchunk=src.qchunk, acc=acc, block=self._block,
+                out_padded=True)
+            if s:
+                tracing.annotate(s, clients=1, nbytes=src.data.nbytes + (
+                    0 if src.scales is None else src.scales.nbytes))
         self._pad_geom = geom
         self._acc = None
         return True

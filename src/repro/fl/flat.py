@@ -33,6 +33,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.utils import tracing
+
 NDArrays = List[np.ndarray]
 
 # ---------------------------------------------------------------------------
@@ -495,14 +497,18 @@ class QuantParams:
 
     def to_flat(self) -> FlatParams:
         """Materialize the logical fp32 FlatParams (one fresh buffer)."""
-        out = FlatParams.zeros(self.layout)
-        mv = out.math_view()
-        tmp = np.empty(min(_QBLOCK, max(self.layout.total_size, 1)),
-                       np.float64)
-        n = self.layout.total_size
-        for lo in range(0, n, _QBLOCK):
-            hi = min(lo + _QBLOCK, n)
-            mv[lo:hi] = self.f64_chunk(lo, hi, tmp)
+        with tracing.outermost("repro.codec.decode") as s:
+            out = FlatParams.zeros(self.layout)
+            mv = out.math_view()
+            tmp = np.empty(min(_QBLOCK, max(self.layout.total_size, 1)),
+                           np.float64)
+            n = self.layout.total_size
+            for lo in range(0, n, _QBLOCK):
+                hi = min(lo + _QBLOCK, n)
+                mv[lo:hi] = self.f64_chunk(lo, hi, tmp)
+            if s:
+                tracing.annotate(s, op="to_flat", codec=self.mode,
+                                 nbytes=self.nbytes())
         return out
 
     def to_arrays(self) -> NDArrays:

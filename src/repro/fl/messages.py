@@ -74,6 +74,7 @@ Lossy codecs are **opt-in and negotiated**, never assumed:
 """
 from __future__ import annotations
 
+import functools
 import struct
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple, Union
@@ -94,6 +95,7 @@ from repro.fl.flat import (FlatParams, Layout, PartialSum, QCHUNK,
                            QuantParams, SparseDelta, WIRE_MAGIC_LO,
                            WIRE_MAGICS, layout_for, np_dtype, quantizable,
                            quantize_int8, topk_indices)
+from repro.utils import tracing
 
 NDArrays = List[np.ndarray]
 
@@ -115,6 +117,7 @@ _MAGIC_BY_CODEC = {"flat": FLAT_MAGIC, "bf16": BF16_MAGIC, "q8": Q8_MAGIC}
 _QUANT_MODE_BY_MAGIC = {BF16_MAGIC: "bf16", Q8_MAGIC: "q8"}
 
 _DEFAULT_CODEC = "flat"
+_CODEC_BY_MAGIC = {m: c for c, m in WIRE_MAGICS.items()}
 
 
 class UnsupportedCodec(ValueError):
@@ -137,6 +140,30 @@ def set_default_codec(name: str) -> str:
         raise ValueError(f"unknown codec {name!r}")
     prev, _DEFAULT_CODEC = _DEFAULT_CODEC, name
     return prev
+
+
+def _codec_span(kind: str, op: str):
+    """Run a public codec function inside a ``repro.codec.<kind>`` span,
+    the outermost on its thread, whose args are read at its end from the
+    frame (an encode's result, a decode's first argument): ``op``, the
+    frame's ``codec`` (its version byte; ``msgpack`` for envelopes and
+    legacy frames) and its length ``nbytes``."""
+    name = f"repro.codec.{kind}"
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def traced(*args, **kw):
+            with tracing.outermost(name) as s:
+                out = fn(*args, **kw)
+                if s:
+                    frame = out if kind == "encode" else args[0]
+                    tracing.annotate(
+                        s, op=op, nbytes=len(frame),
+                        codec=_CODEC_BY_MAGIC.get(frame[0], "msgpack")
+                        if len(frame) else "empty")
+            return out
+        return traced
+    return wrap
 
 
 # ---------------------------------------------------------------------------
@@ -389,6 +416,7 @@ def peek_config(b: bytes) -> Dict[str, Any]:
     return _head_of(b)[0].get("c", {})
 
 
+@_codec_span("decode", "peek_params")
 def peek_params(b: bytes):
     """Zero-copy read-only view of a framed message's parameters
     (FlatParams or QuantParams), or None for legacy/param-less frames.
@@ -405,6 +433,7 @@ def peek_params(b: bytes):
 # ---------------------------------------------------------------------------
 # NDArrays <-> bytes (get_parameters / initial parameters path)
 # ---------------------------------------------------------------------------
+@_codec_span("encode", "arrays")
 def arrays_to_bytes(arrays: NDArrays, codec: Optional[str] = None) -> bytes:
     if (codec or _DEFAULT_CODEC) == "legacy":     # skip the flatten copy
         return msgpack.packb([_pack_array(a) for a in arrays],
@@ -421,7 +450,11 @@ def bytes_to_arrays(b: bytes) -> NDArrays:
 
 # pytree <-> flat NDArrays (clients keep the treedef; the wire sees arrays)
 def params_to_arrays(params) -> NDArrays:
-    return [np.asarray(x) for x in jax.tree.leaves(params)]
+    with tracing.span("repro.xfer.d2h") as s:
+        out = [np.asarray(x) for x in jax.tree.leaves(params)]
+        if s:
+            tracing.annotate(s, nbytes=sum(a.nbytes for a in out))
+    return out
 
 
 def arrays_to_params(arrays: NDArrays, like):
@@ -429,8 +462,14 @@ def arrays_to_params(arrays: NDArrays, like):
     assert len(leaves) == len(arrays), (len(leaves), len(arrays))
     import jax.numpy as jnp
 
-    return jax.tree.unflatten(
-        treedef, [jnp.asarray(a, dtype=l.dtype) for a, l in zip(arrays, leaves)])
+    with tracing.span("repro.xfer.h2d") as s:
+        out = jax.tree.unflatten(
+            treedef,
+            [jnp.asarray(a, dtype=l.dtype) for a, l in zip(arrays, leaves)])
+        if s:
+            tracing.annotate(s, nbytes=sum(getattr(a, "nbytes", 0)
+                                           for a in arrays))
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -488,7 +527,12 @@ class FitRes:
                     "sparse-delta results (0xF5) carry a TopK/adapter "
                     "delta vs a round base held by the server; only "
                     "weighted-sum fit accumulators can fold them")
-            self.parameters = self.quant.to_arrays()
+            with tracing.outermost("repro.codec.decode") as s:
+                self.parameters = self.quant.to_arrays()
+                if s:
+                    tracing.annotate(s, op="materialize",
+                                     codec=self.quant.mode,
+                                     nbytes=self.quant.nbytes())
         return self.parameters
 
 
@@ -563,6 +607,7 @@ def _materialized(p) -> FlatParams:
     return p
 
 
+@_codec_span("encode", "fit_ins")
 def encode_fit_ins(x: FitIns, codec: Optional[str] = None) -> bytes:
     if (codec or _DEFAULT_CODEC) == "legacy":     # skip the flatten copy
         return msgpack.packb({"p": [_pack_array(a) for a in x.parameters],
@@ -571,6 +616,7 @@ def encode_fit_ins(x: FitIns, codec: Optional[str] = None) -> bytes:
                           {"c": _enc_config(x.config)}, codec)
 
 
+@_codec_span("decode", "fit_ins")
 def decode_fit_ins(b: bytes) -> FitIns:
     if _is_framed(b):
         head, p = _unframe(b, writable=True)
@@ -580,6 +626,7 @@ def decode_fit_ins(b: bytes) -> FitIns:
     return FitIns([_unpack_array(a) for a in d["p"]], d["c"])
 
 
+@_codec_span("encode", "fit_res")
 def encode_fit_res(x: FitRes, codec: Optional[str] = None,
                    base: Optional[FlatParams] = None,
                    sparse_frac: float = 0.01,
@@ -602,6 +649,7 @@ def encode_fit_res(x: FitRes, codec: Optional[str] = None,
                           codec, base, sparse_frac, sparse_ranges)
 
 
+@_codec_span("decode", "fit_res")
 def decode_fit_res(b: bytes) -> FitRes:
     if _is_framed(b):
         head, p = _unframe(b)
@@ -640,6 +688,7 @@ def encode_partial_fit_res(ps: PartialSum,
                   np.ascontiguousarray(ps.data).view(np.uint8))
 
 
+@_codec_span("encode", "evaluate_ins")
 def encode_evaluate_ins(x: EvaluateIns, codec: Optional[str] = None) -> bytes:
     if (codec or _DEFAULT_CODEC) == "legacy":     # skip the flatten copy
         return msgpack.packb({"p": [_pack_array(a) for a in x.parameters],
@@ -648,6 +697,7 @@ def encode_evaluate_ins(x: EvaluateIns, codec: Optional[str] = None) -> bytes:
                           {"c": _enc_config(x.config)}, codec)
 
 
+@_codec_span("decode", "evaluate_ins")
 def decode_evaluate_ins(b: bytes) -> EvaluateIns:
     if _is_framed(b):
         head, p = _unframe(b, writable=True)
@@ -657,11 +707,13 @@ def decode_evaluate_ins(b: bytes) -> EvaluateIns:
     return EvaluateIns([_unpack_array(a) for a in d["p"]], d["c"])
 
 
+@_codec_span("encode", "evaluate_res")
 def encode_evaluate_res(x: EvaluateRes) -> bytes:
     return msgpack.packb({"l": float(x.loss), "n": x.num_examples,
                           "m": _enc_config(x.metrics)}, use_bin_type=True)
 
 
+@_codec_span("decode", "evaluate_res")
 def decode_evaluate_res(b: bytes) -> EvaluateRes:
     d = msgpack.unpackb(b, raw=False)
     return EvaluateRes(d["l"], d["n"], d["m"])
@@ -677,11 +729,13 @@ def decode_properties_res(b: bytes) -> Dict[str, Any]:
     return msgpack.unpackb(b, raw=False)
 
 
+@_codec_span("encode", "task_ins")
 def encode_task_ins(t: TaskIns) -> bytes:
     return msgpack.packb({"t": t.task_type, "r": t.round, "p": t.payload,
                           "id": t.task_id, "g": t.group_id}, use_bin_type=True)
 
 
+@_codec_span("decode", "task_ins")
 def decode_task_ins(b: Buffer) -> TaskIns:
     """Accepts any buffer (the TCP SuperNode pull path hands a read-only
     memoryview of the received RES frame straight in — msgpack copies the
@@ -691,11 +745,13 @@ def decode_task_ins(b: Buffer) -> TaskIns:
     return TaskIns(d["t"], d["r"], d["p"], d["id"], d["g"])
 
 
+@_codec_span("encode", "task_res")
 def encode_task_res(t: TaskRes) -> bytes:
     return msgpack.packb({"t": t.task_type, "r": t.round, "p": t.payload,
                           "id": t.task_id, "e": t.error}, use_bin_type=True)
 
 
+@_codec_span("decode", "task_res")
 def decode_task_res(b: Buffer) -> TaskRes:
     d = msgpack.unpackb(b, raw=False)
     return TaskRes(d["t"], d["r"], d["p"], d["id"], d["e"])
