@@ -182,6 +182,7 @@ def _check_fold(strat) -> None:
 
 def one_chip(scale: str = "full") -> None:
     from repro.fl import agg_kernels as K
+    from repro.fl.flat import quant_stats
     from repro.kernels import agg_reduce
     from repro.kernels.platform import interpret_mode
     from repro.launch.mesh import make_local_mesh
@@ -197,7 +198,8 @@ def one_chip(scale: str = "full") -> None:
     t0 = time.perf_counter()
     hist = ex.run(scale, ROUNDS, LOCAL_STEPS, codec="q8", strategy=strat,
                   mesh=mesh)
-    log(f"q8: run wall {time.perf_counter() - t0!r} s")
+    log(f"q8: run wall {time.perf_counter() - t0!r} s; q8 quantizations "
+        f"by engine {quant_stats}")
     codecs = {r.metrics.get("wire_codec") for r in hist.rounds}
     if codecs != {"q8"}:
         fail(f"q8 run negotiated {codecs}")

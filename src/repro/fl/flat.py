@@ -27,12 +27,18 @@ per-chunk accumulate, never materializing a model-size fp32 copy.
 """
 from __future__ import annotations
 
+import functools
 import itertools
+import threading
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+import jax
+import jax.numpy as jnp
+
+from repro.kernels.platform import on_tpu
 from repro.utils import tracing
 
 NDArrays = List[np.ndarray]
@@ -324,6 +330,13 @@ def unflatten_vector(vec: np.ndarray, layout: Layout) -> NDArrays:
 # ---------------------------------------------------------------------------
 QCHUNK = 1024        # elements per int8 scale chunk (fp32 scale each)
 _QBLOCK = 1 << 20    # elements per quantize/dequantize pass (QCHUNK-aligned)
+#: elements per device quantize call (QCHUNK-aligned): 64 MiB of fp32 in,
+#: 16 MiB of int8 out.  Vectors this long or longer quantize on the device.
+SLAB = 1 << 24
+
+#: q8 quantizations per engine since the process started
+quant_stats = {"device": 0, "host": 0}
+_stats_lock = threading.Lock()
 
 
 def quantizable(layout: Layout) -> bool:
@@ -340,7 +353,40 @@ def quantize_int8(vec: np.ndarray, qchunk: int = QCHUNK
     Each ``qchunk``-element window gets scale ``max|x| / 127`` (1.0 for
     all-zero windows), so dequantization error is bounded per coordinate:
     ``|x - scale * q| <= scale / 2``.  Returns ``(q int8, scales fp32)``.
+
+    A vector of at least :data:`SLAB` elements on a TPU is quantized
+    there (:func:`_quantize_int8_device`), anything else on the host
+    (:func:`_quantize_int8_host`); both engines give the same bytes.  The
+    thread's open ``outermost`` span gets the args ``q8_engine`` and
+    ``q8_slabs``.
     """
+    if vec.size >= SLAB and SLAB % qchunk == 0 and on_tpu():
+        engine = "device"
+        q, scales, slabs = _quantize_int8_device(vec, qchunk, SLAB)
+    else:
+        engine, slabs = "host", 0
+        q, scales = _quantize_int8_host(vec, qchunk)
+    with _stats_lock:
+        quant_stats[engine] += 1
+    tracing.annotate_outermost(q8_engine=engine, q8_slabs=slabs)
+    return q, scales
+
+
+def _unit_scales(amax: np.ndarray) -> np.ndarray:
+    """``max|x| / 127`` per window, 1.0 where the window is all zero."""
+    s = (amax / np.float32(127.0)).astype(np.float32)
+    s[s == 0] = np.float32(1.0)
+    return s
+
+
+def _round_q(xs: np.ndarray) -> np.ndarray:
+    """The int8 step of each quotient ``x / scale``."""
+    return np.clip(np.rint(xs), -127, 127).astype(np.int8)
+
+
+def _quantize_int8_host(vec: np.ndarray, qchunk: int
+                        ) -> Tuple[np.ndarray, np.ndarray]:
+    """:func:`quantize_int8` in numpy, ``_QBLOCK`` elements at a time."""
     n = int(vec.size)
     nchunks = -(-n // qchunk)
     scales = np.empty(nchunks, np.float32)
@@ -354,19 +400,95 @@ def quantize_int8(vec: np.ndarray, qchunk: int = QCHUNK
                 if nfull else np.empty(0, np.float32))
         if nfull < hi - lo:                       # ragged tail chunk
             amax = np.append(amax, np.abs(x[nfull:]).max())
-        s = (amax / np.float32(127.0)).astype(np.float32)
-        s[s == 0] = np.float32(1.0)
+        s = _unit_scales(amax)
         c0 = lo // qchunk
         scales[c0:c0 + s.size] = s
         if nfull:       # broadcast one scale per (nchunks, qchunk) row
-            xs = x[:nfull].reshape(-1, qchunk) / s[:nfull // qchunk, None]
-            q[lo:lo + nfull] = np.clip(np.rint(xs), -127, 127) \
-                .astype(np.int8).reshape(-1)
+            q[lo:lo + nfull] = _round_q(
+                x[:nfull].reshape(-1, qchunk) / s[:nfull // qchunk, None]
+            ).reshape(-1)
         if nfull < hi - lo:
-            xt = x[nfull:] / s[-1]
-            q[lo + nfull:hi] = np.clip(np.rint(xt), -127, 127) \
-                .astype(np.int8)
+            q[lo + nfull:hi] = _round_q(x[nfull:] / s[-1])
     return q, scales
+
+
+#: a quotient this close to a half-integer, relative to ``|t| + 1``, may
+#: round otherwise on a device whose division is not correctly rounded:
+#: a TPU v5e's fp32 division was off by up to 2 ulps, the band is 8 or more
+_TIE_BAND = 2.0 ** -20
+
+
+def _windows(x, qchunk: int):
+    """``(windows, rows, lanes)`` view of a slab: a 1024-element window is
+    one (8, 128) tile of the chip's layout, so the view is free there."""
+    lanes = 128 if qchunk % 128 == 0 else qchunk
+    return x.reshape(-1, qchunk // lanes, lanes)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _slab_amax(x, qchunk: int):
+    """Each window's ``max|x|`` (a max rounds nothing: exact)."""
+    return jnp.max(jnp.abs(_windows(x, qchunk)), axis=(1, 2))
+
+
+@jax.jit
+def _slab_q(x, scales):
+    """``clip(rint(x / scale), -127, 127)``, and per window whether any
+    quotient lies in the tie band, where the host must round it."""
+    t = _windows(x, x.size // scales.size) / scales[:, None, None]
+    r = jnp.rint(t)
+    near = jnp.abs(jnp.abs(t - r) - 0.5) <= (jnp.abs(t) + 1) * _TIE_BAND
+    q = jnp.clip(r, -127, 127).astype(jnp.int8).reshape(-1)
+    return q, jnp.any(near, axis=(1, 2))
+
+
+def _quantize_int8_device(vec: np.ndarray, qchunk: int, slab: int
+                          ) -> Tuple[np.ndarray, np.ndarray, int]:
+    """:func:`quantize_int8` on the default device, ``slab`` elements a
+    call; returns ``(q, scales, slabs)``, bitwise the host engine's.
+
+    Per slab: its windows' ``max|x|`` on the device; the scales finished
+    on the host from them, as the host engine does; then ``q`` on the
+    device, copied back while the next slab's maxima run, so at most two
+    slabs of one call are on the device at a time.  A window with
+    a quotient in the tie band is quantized again on the host.  The last
+    slab is zero-padded (zeros change no window's max) and its padding
+    dropped.  Input that is not fp32 is cast on the host, slab by slab.
+    A device that flushes subnormals (a TPU) reads a window whose largest
+    magnitude is subnormal as all zero: scale 1.0, ``q`` 0.
+    """
+    n = int(vec.size)
+    nslabs = -(-n // slab)
+    q = np.empty(nslabs * slab, np.int8)
+    scales = np.empty(nslabs * slab // qchunk, np.float32)
+
+    def drain(lo, x, s, qd, near):
+        out = q[lo:lo + slab]
+        out[:] = np.asarray(qd)
+        redo = np.flatnonzero(np.asarray(near))
+        if redo.size:
+            out.reshape(-1, qchunk)[redo] = _round_q(
+                x.reshape(-1, qchunk)[redo] / s[redo, None])
+
+    pending = None
+    for lo in range(0, n, slab):
+        hi = min(lo + slab, n)
+        if hi - lo == slab:
+            x = np.asarray(vec[lo:hi], np.float32)
+        else:
+            x = np.zeros(slab, np.float32)
+            x[:hi - lo] = vec[lo:hi]
+        xd = jax.device_put(x)
+        s = _unit_scales(np.asarray(_slab_amax(xd, qchunk)))
+        scales[lo // qchunk:(lo + slab) // qchunk] = s
+        qd, near = _slab_q(xd, s)
+        qd.copy_to_host_async()
+        if pending is not None:
+            drain(*pending)
+        pending = (lo, x, s, qd, near)
+    if pending is not None:
+        drain(*pending)
+    return q[:n], scales[:-(-n // qchunk)], nslabs
 
 
 def _dequant_q8(data: np.ndarray, scales: np.ndarray, qchunk: int,

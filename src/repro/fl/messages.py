@@ -17,9 +17,13 @@ codecs coexist behind a leading version byte:
   (4x vs fp32) with per-coordinate error bounded by ``scale/2``.  Fit
   results are encoded as **deltas** against the round-start parameters
   (header flag ``d``), which keeps the quantization bound proportional to
-  the *update* magnitude, not the weights.  Both lossy frames decode
-  zero-copy into :class:`~repro.fl.flat.QuantParams`, which the
-  aggregation kernels stream through fused dequantize+accumulate reads.
+  the *update* magnitude, not the weights.  The quantizer is
+  :func:`~repro.fl.flat.quantize_int8`: a model-size vector on a TPU
+  host is quantized on the chip, slab by slab, anything else (a CPU
+  host, a small vector) by numpy; both give the same bytes.  Both lossy
+  frames decode zero-copy into :class:`~repro.fl.flat.QuantParams`,
+  which the aggregation kernels stream through fused
+  dequantize+accumulate reads.
 - **partial** (magic ``0xF4``): an edge aggregator's pre-reduced subtree
   sum — one raw fp64 ``Σw·x`` vector plus total weight / contributing
   node ids in the header (:class:`~repro.fl.flat.PartialSum`).  Lossless

@@ -15,6 +15,8 @@ computes no argument and syncs nothing unless a trace is being taken.
 ``outermost`` is a span that does not nest: while one is open on a
 thread, the next ``outermost`` on that thread is the no-op, so a codec
 function that calls another codec function is counted once.
+``annotate_outermost`` lets the code under such a span add arguments to
+it without holding it.
 
 There is no sink or setting: the profiler is the one exporter.
 """
@@ -48,16 +50,24 @@ def annotate(s, **args) -> None:
 
 class _Outermost(TraceAnnotation):
     def __enter__(self):
-        _local.open = True
+        _local.open = self
         return super().__enter__()
 
     def __exit__(self, *exc):
-        _local.open = False
+        _local.open = None
         return super().__exit__(*exc)
 
 
 def outermost(name: str, **args):
     """``span``, unless an ``outermost`` span is open on this thread."""
-    if not TraceAnnotation.is_enabled() or getattr(_local, "open", False):
+    if not TraceAnnotation.is_enabled() or \
+            getattr(_local, "open", None) is not None:
         return OFF
     return _Outermost(name, **args)
+
+
+def annotate_outermost(**args) -> None:
+    """Set ``args`` on this thread's open ``outermost`` span, if any."""
+    s = getattr(_local, "open", None)
+    if s is not None:
+        s.set_metadata(**args)

@@ -10,6 +10,7 @@ v5e chip, the kernels and the fit step that a federated round runs at
   payloads and the accumulator-continuation form, ``sort_reduce`` and
   ``gram`` at 16 clients, the sparse dequant graph);
 - ``flash_attention`` at 12 heads x 64 head dim x 256 tokens;
+- the wire codec's slab quantizer at ``repro.fl.flat.SLAB``;
 - the full-scale train step, whose compiled memory must fit 16 GB.
 
 The topology is described inside a module fixture, never at import: only
@@ -121,6 +122,23 @@ def test_sparse_dequant_compiles(one_chip, no_cache):
     n = 1 << 22
     A.dequant_q8.lower(_shape(one_chip, (n,), jnp.int8),
                        _shape(one_chip, (n,), jnp.float32)).compile()
+
+
+def test_slab_quantizer_compiles_and_fits(one_chip, no_cache):
+    """The wire codec's device q8 engine at the module's ``SLAB``: its two
+    programs (window maxima, then ``q``) hold under the 2 x 80 MiB that
+    two slabs in flight may take."""
+    from repro.fl import flat
+
+    x = _shape(one_chip, (flat.SLAB,), jnp.float32)
+    s = _shape(one_chip, (flat.SLAB // flat.QCHUNK,), jnp.float32)
+    used = 0
+    for compiled in (flat._slab_amax.lower(x, flat.QCHUNK).compile(),
+                     flat._slab_q.lower(x, s).compile()):
+        mem = compiled.memory_analysis()
+        used += (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                 + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0 < used < 2 * 80 * 1024 ** 2, used
 
 
 def test_flash_attention_compiles(one_chip, no_cache):

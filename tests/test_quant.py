@@ -5,7 +5,8 @@ Covers the contracts the compressed hot path rests on:
   legacy/0xF1/0xF2/0xF3 round-trips (bitwise for the lossless pair,
   within the quantization bound for the lossy ones) or raises a clear
   ``UnsupportedCodec`` for reserved version bytes this build lacks;
-- the int8 per-chunk quantization error bound (hypothesis property);
+- the int8 per-chunk quantization error bound (hypothesis property) of
+  both engines, host and device, and their bitwise-equal scales;
 - zero-copy decode of compressed frames (data/scales are views);
 - delta encoding: client and server agree bitwise on the round base,
   reconstruction error is bounded by the *update* magnitude;
@@ -25,6 +26,7 @@ try:
 except ImportError:                      # bare env: deterministic shim
     from _hypothesis_fallback import given, settings, strategies as st
 
+from repro.fl import flat
 from repro.fl.flat import (FlatParams, QCHUNK, QuantParams, quantizable,
                            quantize_int8, layout_of)
 from repro.fl.messages import (FLAT_MAGIC, BF16_MAGIC, Q8_MAGIC, FitIns,
@@ -51,28 +53,155 @@ def _q8_bound(q: QuantParams) -> float:
 
 
 # ---------------------------------------------------------------------------
-# int8 quantization primitive
+# int8 quantization primitive: the host and device engines
 # ---------------------------------------------------------------------------
-@settings(max_examples=25, deadline=None)
-@given(st.integers(1, 3 * QCHUNK + 7), st.integers(0, 10_000),
-       st.floats(1e-6, 1e3))
-def test_int8_quantization_error_bound(n, seed, magnitude):
+#: the device engine's slab in these tests (the module's SLAB is 2^24):
+#: small, so every case runs on the CPU backend in well under a second
+TEST_SLAB = 2 * QCHUNK
+ENGINES = {
+    "host": lambda x: flat._quantize_int8_host(x, QCHUNK),
+    "device": lambda x: flat._quantize_int8_device(x, QCHUNK, TEST_SLAB)[:2],
+}
+
+
+@pytest.mark.parametrize("engine", sorted(ENGINES))
+@pytest.mark.parametrize("case", ["random", "all_zero_chunks"])
+def test_int8_quantization_error_bound(case, engine):
     """|x - scale*q| <= scale/2 per coordinate, any length (ragged tails
-    included), any dynamic range."""
-    rng = np.random.default_rng(seed)
-    x = (rng.normal(0, magnitude, size=n)).astype(np.float32)
+    and slab tails included), any dynamic range; all-zero chunks get
+    scale 1.0 and q 0."""
+    quantize = ENGINES[engine]
+    if case == "all_zero_chunks":
+        q, scales = quantize(np.zeros(2 * QCHUNK + 5, np.float32))
+        assert (scales == 1.0).all() and (q == 0).all()
+        return
+
+    @settings(max_examples=25, deadline=None)
+    @given(st.integers(1, 3 * QCHUNK + 7), st.integers(0, 10_000),
+           st.floats(1e-6, 1e3))
+    def bounded(n, seed, magnitude):
+        rng = np.random.default_rng(seed)
+        x = (rng.normal(0, magnitude, size=n)).astype(np.float32)
+        q, scales = quantize(x)
+        assert q.dtype == np.int8 and scales.dtype == np.float32
+        assert q.size == n
+        assert scales.size == -(-n // QCHUNK) and (scales > 0).all()
+        sv = np.repeat(scales.astype(np.float64), QCHUNK)[:n]
+        err = np.abs(q.astype(np.float64) * sv - x.astype(np.float64))
+        bound = sv * 0.5 * (1 + 1e-5) + 1e-12
+        assert (err <= bound).all(), float((err - bound).max())
+
+    bounded()
+
+
+def _agreement_input(case):
+    rng = np.random.default_rng(5)
+    n = {"one_slab": TEST_SLAB, "ragged_tail": 2 * TEST_SLAB + 3 * QCHUNK // 2 + 7,
+         "zero_slab": 3 * TEST_SLAB - 100, "fp64": 2 * TEST_SLAB + 901}[case]
+    x = rng.normal(0, 1e-2, size=n)
+    x[::97] *= 1e3                     # windows of very different ranges
+    if case == "zero_slab":
+        x[TEST_SLAB:2 * TEST_SLAB] = 0.0
+    return x if case == "fp64" else x.astype(np.float32)
+
+
+@pytest.mark.parametrize("case", ["one_slab", "ragged_tail", "zero_slab",
+                                  "fp64"])
+def test_device_engine_agrees_with_the_host_engine(case):
+    """The device engine gives the host engine's bytes, every time."""
+    x = _agreement_input(case)
+    qh, sh = ENGINES["host"](x)
+    qd, sd = ENGINES["device"](x)
+    assert sd.dtype == sh.dtype and sd.tobytes() == sh.tobytes()
+    assert qd.dtype == qh.dtype and qd.tobytes() == qh.tobytes()
+    again = ENGINES["device"](x)
+    assert again[0].tobytes() == qd.tobytes()
+    assert again[1].tobytes() == sd.tobytes()
+
+
+def test_windows_in_the_tie_band_are_rounded_on_the_host(monkeypatch):
+    """A window with a quotient at a rounding tie is flagged, one of
+    whole quotients is not; a device that rounds the flagged windows
+    otherwise still yields the host engine's bytes."""
+    import jax.numpy as jnp
+
+    ties = np.zeros(QCHUNK, np.float32)        # scale 1: x is the quotient
+    ties[0], ties[1:128] = 127.0, np.arange(127) + 0.5
+    whole = (np.arange(QCHUNK) % 255 - 127).astype(np.float32)
+    x = np.concatenate([ties, whole])
+    s = flat._unit_scales(np.asarray(flat._slab_amax(x, QCHUNK)))
+    assert np.asarray(flat._slab_q(x, s)[1]).tolist() == [True, False]
+
+    slab_q = flat._slab_q
+
+    def rounds_flagged_windows_up(xd, scales):
+        qd, near = slab_q(xd, scales)
+        up = jnp.minimum(qd.reshape(near.size, -1).astype(jnp.int32) + 1, 127)
+        qd = jnp.where(near[:, None], up, qd.reshape(near.size, -1))
+        return qd.astype(jnp.int8).reshape(-1), near
+
+    monkeypatch.setattr(flat, "_slab_q", rounds_flagged_windows_up)
+    qh, sh = ENGINES["host"](x)
+    qd, sd = ENGINES["device"](x)
+    assert sd.tobytes() == sh.tobytes() and qd.tobytes() == qh.tobytes()
+
+
+@pytest.mark.parametrize("platform,n,engine", [
+    ("tpu", TEST_SLAB, "device"),
+    ("tpu", TEST_SLAB + 5, "device"),
+    ("tpu", TEST_SLAB - 1, "host"),
+    ("cpu", TEST_SLAB, "host"),
+])
+def test_quantize_int8_picks_the_engine(monkeypatch, platform, n, engine):
+    """A TPU and a vector of at least SLAB elements take the device
+    engine; a CPU platform or a shorter vector the host one."""
+    monkeypatch.setattr(flat, "SLAB", TEST_SLAB)
+    monkeypatch.setattr(flat, "on_tpu", lambda: platform == "tpu")
+    x = _agreement_input("ragged_tail")[:n]
+    before = dict(flat.quant_stats)
     q, scales = quantize_int8(x)
-    assert q.dtype == np.int8 and scales.dtype == np.float32
-    assert scales.size == -(-n // QCHUNK) and (scales > 0).all()
-    sv = np.repeat(scales.astype(np.float64), QCHUNK)[:n]
-    err = np.abs(q.astype(np.float64) * sv - x.astype(np.float64))
-    bound = sv * 0.5 * (1 + 1e-5) + 1e-12
-    assert (err <= bound).all(), float((err - bound).max())
+    assert {k: v - before[k] for k, v in flat.quant_stats.items()} == {
+        e: int(e == engine) for e in ("device", "host")}
+    qh, sh = ENGINES["host"](x)
+    assert scales.tobytes() == sh.tobytes() and q.tobytes() == qh.tobytes()
 
 
-def test_int8_all_zero_chunks_use_unit_scale():
-    q, scales = quantize_int8(np.zeros(2 * QCHUNK + 5, np.float32))
-    assert (scales == 1.0).all() and (q == 0).all()
+def test_concurrent_quantizes_are_exact_and_counted(monkeypatch):
+    """More threads than cores quantize at once, half on each engine:
+    every result is the host engine's and no count is lost."""
+    import sys
+    import threading
+
+    monkeypatch.setattr(flat, "SLAB", TEST_SLAB)
+    monkeypatch.setattr(flat, "on_tpu", lambda: True)
+    x = _agreement_input("ragged_tail")
+    want = [ENGINES["host"](x[:n]) for n in (x.size, TEST_SLAB - 1)]
+    before = dict(flat.quant_stats)
+    bad = []
+
+    def work(i):
+        for _ in range(5):
+            n = x.size if i % 2 else TEST_SLAB - 1
+            q, s = quantize_int8(x[:n])
+            wq, ws = want[0 if i % 2 else 1]
+            if q.tobytes() != wq.tobytes() or s.tobytes() != ws.tobytes():
+                bad.append(i)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=work, args=(i,))
+                   for i in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert not bad
+    assert {k: v - before[k] for k, v in flat.quant_stats.items()} == {
+        "device": 40, "host": 40}
 
 
 # ---------------------------------------------------------------------------

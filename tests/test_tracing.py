@@ -4,8 +4,8 @@ A two-round, two-site q8 FedAvg federation runs through ``run_in_flare``
 with the example's ``LMClient`` on a one-layer model and the Pallas fold,
 inside ``jax.profiler.trace``; the trace is read back with
 ``ProfileData`` and the benchmark's ``program_spans.host_events``.  It
-must hold every program span with its arguments, codec spans must not
-nest on a thread, and every task the server pushed must come back from
+must hold every program span with its arguments (a q8 encode also the
+engine that quantized it), codec spans must not nest on a thread, and every task the server pushed must come back from
 exactly one pull that reports ``hit=1``.
 """
 import glob
@@ -160,6 +160,17 @@ def test_a_traced_federation_holds_every_span_with_its_args(traced):
     assert all(a["queued_s"] >= 0
                for a in by_name["repro.superlink.deliver"])
     assert {a["clients"] for a in by_name["repro.fold.stage"]} == {2}
+
+
+def test_every_q8_encode_names_its_quantizer_engine(traced):
+    """Each q8 frame's encode span says which engine quantized it and in
+    how many device slabs: on the CPU, the host engine and none."""
+    events, _ = traced
+    q8 = [a for _, name, _, _, a in events
+          if name == "repro.codec.encode" and a["codec"] == "q8"]
+    assert {a["op"] for a in q8} >= {"fit_ins", "fit_res", "evaluate_ins"}
+    assert all((a.get("q8_engine"), a.get("q8_slabs")) == ("host", 0)
+               for a in q8), q8
 
 
 def test_codec_spans_do_not_nest_on_a_thread(traced):
