@@ -6,9 +6,10 @@ benchmark run.
     python3 benchmarks/chip/calibrate.py --workload W --seeds 12 \\
         --control-seeds 3 --first-seed 1000
 
-For every seed, a fresh set of the benchmark's clients runs the cell's
-first two rounds without the relay: every site fits from the q8 downlink
-of the seeded weights, FedAvg folds their q8 uplinks, ``end_of_setup``
+For every seed, a fresh set of the benchmark's clients, on the cell's
+device layout (``federation.layout``), runs the cell's first two rounds
+without the relay: every site fits from the q8 downlink of the seeded
+weights, FedAvg folds their q8 uplinks, ``end_of_setup``
 runs as in a benchmark run, and the checked round follows: every site
 fits from the downlink of the new model through the window's branch of
 ``fit`` (the carried optimizer state), FedAvg folds, and site 1
@@ -50,10 +51,11 @@ FIT = ("fit_loss_gap", "fit_grad_gap", "fit_grad_median_gap",
 NUMBERS = (*FIT, "eval_loss_gap", "uplink_q8_err", "fold_err_over_bound")
 
 
-def _round(clients, model, rnd: int, record=None):
+def _round(clients, model, rnd: int, shard_mesh, record=None):
     """One federated round without the relay: every client fits from the
-    q8 downlink of ``model``, FedAvg folds the q8 uplinks.  Returns the
-    new model; ``record.fold`` gets the fold as a run records it."""
+    q8 downlink of ``model``, FedAvg folds the q8 uplinks (on
+    ``shard_mesh``, as ``federation.run``'s server does).  Returns the new
+    model; ``record.fold`` gets the fold as a run records it."""
     from repro.fl import FedAvg
     from repro.fl.messages import (FitIns, FitRes, decode_fit_ins,
                                    decode_fit_res, encode_fit_ins,
@@ -62,7 +64,7 @@ def _round(clients, model, rnd: int, record=None):
     down = encode_fit_ins(FitIns(model, {"round": rnd, "codec": "q8"}),
                           codec="q8")
     base = peek_params(down)
-    acc = FedAvg().fit_accumulator(rnd, model)
+    acc = FedAvg(shard_mesh=shard_mesh).fit_accumulator(rnd, model)
     arrivals = []
     for cl in clients:
         ins = decode_fit_ins(down)
@@ -94,13 +96,15 @@ def one_seed(cell, prep, seed: int, control: bool) -> dict:
     loader = traffic.SiteTokens(mix, c["vocab_size"], seed)
     Client = federation.client_class(federation._example().LMClient)
     rec = federation.Record(beta1=prep["tcfg"].beta1)
+    mesh, shard_mesh = federation.layout(cell["workload"]["chips"])
     clients = [Client(f"site-{s + 1}", prep["cfg"], prep["tcfg"], loader,
-                      mix["local_steps"]) for s in range(mix["sites"])]
+                      mix["local_steps"], mesh=mesh)
+               for s in range(mix["sites"])]
     clients[0].record = rec
-    model = _round(clients, init, 1)
+    model = _round(clients, init, 1, shard_mesh)
     federation.end_of_setup(clients[0], model)
     rec.start_model = model
-    new = _round(clients, model, federation.CHECKED_ROUND, rec)
+    new = _round(clients, model, federation.CHECKED_ROUND, shard_mesh, rec)
     ev = decode_evaluate_ins(encode_evaluate_ins(
         EvaluateIns(new, {"round": federation.CHECKED_ROUND}), codec="q8"))
     clients[0].evaluate(ev.parameters, ev.config)
@@ -163,9 +167,10 @@ def main() -> None:
 
     import jax
 
-    if jax.devices()[0].platform != "tpu":
-        print(f"calibration runs on a TPU; JAX has {jax.devices()}",
-              file=sys.stderr)
+    chips = cell["workload"]["chips"]
+    if jax.devices()[0].platform != "tpu" or len(jax.devices()) < chips:
+        print(f"calibration runs on {chips} TPU chip(s); JAX has "
+              f"{jax.devices()}", file=sys.stderr)
         sys.exit(3)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     prep = harness.prepare(cell)

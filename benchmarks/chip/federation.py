@@ -28,6 +28,11 @@ end inside ``seconds`` (by the last round's length); after that every
 ``configure_fit`` and ``configure_evaluate`` returns no tasks and the
 fold hands back the current model, so the remaining rounds are empty.
 
+The device layout follows the cell's chips (``layout``): on one chip,
+the program's defaults; on several, each site's fit is FSDP-sharded over
+a (data=chips) mesh and the fold's accumulator is split into one range
+per chip.
+
 What the check reads (``Record``) comes from the window's first round:
 site 1's first steps through the window's own branch of ``fit`` (the
 round's weights swapped into the carried state), its uplink, its
@@ -60,6 +65,20 @@ ROOT = pathlib.Path(__file__).resolve().parents[2]
 WARMUP_ROUNDS = 1
 CHECKED_ROUND = WARMUP_ROUNDS + 1
 RECORDED_STEPS = 3
+
+
+def layout(chips: int):
+    """``(mesh, shard_mesh)`` of a cell on ``chips`` chips.  One chip:
+    neither, so each client takes the program's default (1,1) mesh and
+    the fold its unsharded accumulator.  More: every site's train step
+    on a (data=chips) mesh (the program's FSDP path: params and Adam
+    moments sharded over the chips) and the fold's fp64 accumulator in
+    one range per chip."""
+    if chips == 1:
+        return None, None
+    from repro.launch.mesh import make_agg_mesh, make_local_mesh
+
+    return make_local_mesh(data=chips, model=1), make_agg_mesh(chips)
 
 
 def _example():
@@ -237,6 +256,7 @@ class WindowedFedAvg(FedAvg):
         self.round_t: Dict[int, float] = {}         # round -> seconds
         self.window_rounds: List[int] = []
         self.attempted = 0
+        self.fold_shards: Optional[int] = None    # of the last fold
         self._round_start: Dict[int, float] = {}
         self._round_span: Dict[int, Any] = {}
         self._model: Any = None
@@ -284,6 +304,10 @@ class WindowedFedAvg(FedAvg):
         def timed_finalize(failures):
             with span("bench.fold", op="finalize"):
                 out = finalize(failures)
+            # the accumulator a sharded fold streams into; an unsharded
+            # fold has none (0 ranges)
+            streaming = getattr(acc, "_streaming", None)
+            self.fold_shards = streaming.shards if streaming else 0
             self._model = out[0]
             if rnd == CHECKED_ROUND and self.record is not None:
                 self.record.fold = {"out": out[0], "arrivals": sorted(
@@ -315,11 +339,13 @@ class WindowedFedAvg(FedAvg):
 
 
 def run(cfg, tcfg, mix: Dict, loader, initial_parameters, seconds: float,
-        on_window_open=None, on_window_close=None,
+        chips: int, on_window_open=None, on_window_close=None,
         record: Optional[Record] = None):
-    """One federation: ``WARMUP_ROUNDS`` of set-up, then the window.
-    Returns ``(history, strategy, clients)``."""
+    """One federation on ``chips`` chips (``layout``): ``WARMUP_ROUNDS``
+    of set-up, then the window.  Returns ``(history, strategy,
+    clients)``."""
     ex = _example()
+    mesh, shard_mesh = layout(chips)
     Client = client_class(ex.LMClient)
     sites = [f"site-{i + 1}" for i in range(mix["sites"])]
     clients: List[Any] = []
@@ -327,7 +353,8 @@ def run(cfg, tcfg, mix: Dict, loader, initial_parameters, seconds: float,
 
     def client_app_fn(site):
         def make(cid):
-            c = Client(site, cfg, tcfg, loader, mix["local_steps"])
+            c = Client(site, cfg, tcfg, loader, mix["local_steps"],
+                       mesh=mesh)
             if site == sites[0]:
                 c.record = record
                 first.append(c)
@@ -350,7 +377,7 @@ def run(cfg, tcfg, mix: Dict, loader, initial_parameters, seconds: float,
             rt.provision_site(s)
         server = ServerApp(
             config=ServerConfig(num_rounds=rounds, round_timeout=3600,
-                                codec=mix["codec"]),
+                                codec=mix["codec"], shard_mesh=shard_mesh),
             strategy=strategy)
         history = run_in_flare(rt, server, client_app_fn, sites,
                                timeout=7200)

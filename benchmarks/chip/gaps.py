@@ -15,19 +15,18 @@ one JSON line:
   ``bench.*`` span over it, so four sites' fits count four times, else
   to a SuperLink serve or empty pull, else to ``relay_codec``, time no
   span covers), and ``by_layer``, those summed by layer;
-- ``idle_gaps``: each device's ten longest idle gaps as ``[label,
-  trace_reduce.label, seconds]``, longest first: the first label counts
-  the program's spans (``program_spans.label``), the second is the one
-  ``run.py``'s breakdown reports.
+- ``device_ops``, ``idle_gaps``: ``run.py``'s breakdown, the ten device
+  ops that took most time and the ten longest idle gaps over the chips
+  as ``[label, seconds]``, labelled by ``program_spans.label``.
 
-A trace without program spans gives ``trace_reduce.label``'s labels
-twice.  Exits 1 if there is no trace.
+Exits 1 if there is no trace.
 """
 import json
 import sys
 from typing import Dict
 
 import program_spans as ps
+import run
 import trace_reduce as tr
 
 NAMES = ("bench.fit", "bench.eval", "bench.fold")
@@ -51,15 +50,11 @@ def report(t: tr.Trace) -> dict:
     by_layer: Dict[str, float] = {}
     for k, v in split.items():
         by_layer[layer(k)] = by_layer.get(layer(k), 0.0) + v
-    gaps = [[ps.label(t, g, NAMES), tr.label(t, g, NAMES),
-             (g[1] - g[0]) * 1e-9]
-            for dev in t.ops for g in tr.gaps(t, dev, lo, hi)[:10]]
-    gaps.sort(key=lambda x: -x[2])
     return {"window_s": (hi - lo) * 1e-9, "rounds": len(t.rounds),
             "split": dict(sorted(split.items(), key=lambda kv: -kv[1])),
             "by_layer": dict(sorted(by_layer.items(),
                                     key=lambda kv: -kv[1])),
-            "idle_gaps": gaps[:10]}
+            **run.breakdown(t, lo, hi)}
 
 
 def main() -> int:

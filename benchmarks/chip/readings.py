@@ -25,6 +25,7 @@ class Context:
     peaks: Dict[str, float]       # flops.peaks(device_kind)
     fit_flops_per_round: float    # train steps of every site
     eval_flops_per_round: float   # eval forwards of every site
+    chips: int = 1                # the cell's; each step runs on all
 
     @property
     def window(self) -> Optional[Tuple[int, int]]:

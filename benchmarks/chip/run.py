@@ -16,7 +16,9 @@ window round's outputs against the plain reference (``compare``,
 ``--trace 0`` reports the end-to-end metrics: ``round_s``, the window's
 wall time over its rounds, and ``setup_s``, process start to the window.
 ``--trace 1`` traces the window instead and reports the per-layer metrics
-and a breakdown of device time and idle gaps.
+and a breakdown of device time and idle gaps.  The cell's ``chips`` set
+the federation's device layout (``federation.layout``); the device record
+gives the peak memory of the fullest of them.
 
 Stdout's last line is the result object; stderr's last lines are each
 compared number beside its limit.  Without a TPU, or with fewer chips than
@@ -233,10 +235,12 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         if trace:
             jax.profiler.stop_trace()
 
+    chips = cell["workload"]["chips"]
     agg_kernels.reset_fallback_counts()
     history, strat, clients = federation.run(
-        cfg, tcfg, mix, loader, init, seconds, on_window_open=window_open,
-        on_window_close=window_close, record=record)
+        cfg, tcfg, mix, loader, init, seconds, chips,
+        on_window_open=window_open, on_window_close=window_close,
+        record=record)
     n_rounds = len(strat.window_rounds)
     round_s = (strat.window_close_t - strat.window_open_t) / n_rounds
     setup_s = strat.window_open_t - t0
@@ -251,17 +255,15 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     log(f"inside the window: {d_compile} compiles (cache loads included), "
         f"{d_trace} traces")
     info = agg_reduce.wsum_fn.cache_info()
-    log(f"fold: backend {agg_kernels.default_backend()}, kernel programs "
-        f"{info.currsize}, numpy fallbacks {agg_kernels.fallback_counts()}")
+    log(f"fold: backend {agg_kernels.default_backend()}, accumulator "
+        f"ranges {strat.fold_shards}, kernel programs {info.currsize}, "
+        f"numpy fallbacks {agg_kernels.fallback_counts()}")
+    log(f"sites' train states: {state_layout(clients)}")
     evals = [(r.round, r.loss) for r in history.rounds
              if r.loss is not None]
     log(f"federated eval loss by round: {evals}")
 
-    dev = jax.devices()[0]
-    stats = dev.memory_stats() or {}
-    device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": len(jax.devices()),
-              "memory_peak_bytes": stats.get("peak_bytes_in_use")}
+    device = device_record(jax.devices(), chips)
 
     t_done = time.perf_counter()
     # free the program's device state before the reference runs
@@ -292,13 +294,13 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         log(f"traced run: round {round_s!r} s, set-up {setup_s!r} s")
         tr = trace_reduce.load(str(next(TRACE_DIR.glob(
             "plugins/profile/*/*.xplane.pb"))))
-        pk = flops.peaks(dev.device_kind)
+        pk = flops.peaks(device["kind"])
         step = flops.train_step_flops(c, mix["batch"], mix["seq_len"])
         fwd = flops.forward_flops(c, mix["batch"], mix["seq_len"])
         ctx = readings.Context(
             trace=tr, cfg=c, mix=mix, peaks=pk,
             fit_flops_per_round=step * mix["sites"] * mix["local_steps"],
-            eval_flops_per_round=fwd * mix["sites"])
+            eval_flops_per_round=fwd * mix["sites"], chips=chips)
         metrics = {}
         for m in cell["per_layer"]:
             v = readings.read(m["name"], ctx)
@@ -317,7 +319,39 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     return result
 
 
+def device_record(devices, chips: int) -> dict:
+    """The result's ``device``: as JAX reports it, with the peak memory
+    of the fullest of the cell's ``chips`` devices and each one's."""
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in devices[:chips]]
+    return {"platform": devices[0].platform, "kind": devices[0].device_kind,
+            "count": len(devices),
+            "memory_peak_bytes": max(peaks, key=lambda b: b or 0),
+            "memory_peak_bytes_by_device": peaks}
+
+
+def state_layout(clients) -> dict:
+    """Per site: how many devices the least spread leaf of its train
+    state lives on, and the share of the state's bytes on its fullest
+    device."""
+    import jax
+
+    out = {}
+    for cl in clients:
+        leaves = jax.tree.leaves(cl._state)
+        on = {}
+        for leaf in leaves:
+            for s in leaf.addressable_shards:
+                on[s.device] = on.get(s.device, 0) + s.data.nbytes
+        total = sum(leaf.nbytes for leaf in leaves)
+        out[cl.site] = {
+            "devices": min(len(leaf.sharding.device_set) for leaf in leaves),
+            "fullest_share": max(on.values()) / total}
+    return dict(sorted(out.items()))
+
+
 def breakdown(tr, lo, hi) -> dict:
+    import program_spans
     import trace_reduce
 
     by_op = {}
@@ -330,7 +364,7 @@ def breakdown(tr, lo, hi) -> dict:
     gaps = []
     for dev in tr.ops:
         for g in trace_reduce.gaps(tr, dev, lo, hi)[:10]:
-            gaps.append([trace_reduce.label(
+            gaps.append([program_spans.label(
                 tr, g, ("bench.fit", "bench.eval", "bench.fold")),
                 (g[1] - g[0]) * 1e-9])
     gaps.sort(key=lambda x: -x[1])

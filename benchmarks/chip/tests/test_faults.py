@@ -83,6 +83,70 @@ def uplink_altered(monkeypatch):
     monkeypatch.setattr(messages, "quantize_int8", altered)
 
 
+def fit_exchange_left_out(monkeypatch):
+    """The exchange between chips left out of a sharded fit step: each
+    chip steps its own shard of the state (params and Adam moments) on
+    its own rows of the batch alone, and no gradient crosses to another
+    chip; a leaf that no chip shards keeps the first chip's copy."""
+    import jax
+
+    from repro.launch.shardings import state_shardings
+    from repro.models import build_model
+    from repro.train import steps
+
+    get = steps.get_train_step
+
+    def faulty(model_cfg, train_cfg, impl="xla", mesh=None):
+        model = build_model(model_cfg)
+        alone = jax.jit(steps.make_train_step(model, train_cfg, impl=impl))
+        where = state_shardings(model, train_cfg, mesh)
+        n = mesh.shape["data"]
+
+        def data_axis(sh):
+            return next((d for d, a in enumerate(sh.spec)
+                         if "data" in (a if isinstance(a, tuple) else (a,))),
+                        None)
+
+        def gather(sh, *per_chip):
+            d = data_axis(sh)
+            if d is None:
+                return jax.device_put(per_chip[0], sh)
+            return jax.device_put(np.concatenate(
+                [np.array_split(x, n, axis=d)[i]
+                 for i, x in enumerate(per_chip)], axis=d), sh)
+
+        def step(state, batch):
+            # on the host and one device: no program spans the chips
+            state = jax.device_get(state)
+            rows = batch["tokens"].shape[0] // n
+            outs = [jax.device_get(alone(state, {
+                k: v[i * rows:(i + 1) * rows] for k, v in batch.items()}))
+                for i in range(n)]
+            return (jax.tree.map(gather, where, *[o[0] for o in outs]),
+                    outs[0][1])
+
+        return step if mesh is not None else get(model_cfg, train_cfg,
+                                                 impl=impl, mesh=mesh)
+
+    monkeypatch.setattr(steps, "get_train_step", faulty)
+
+
+def fold_exchange_left_out(monkeypatch):
+    """The exchange between chips left out of a sharded fold: only the
+    first chip's range of the accumulator is gathered into the new
+    model; the other ranges' partial sums never arrive, so they keep the
+    round's base."""
+    from repro.fl.agg_kernels import StreamingWeightedSum
+
+    acc = StreamingWeightedSum._shard_acc
+
+    def first_only(self, si):
+        a = acc(self, si)
+        return a if si == 0 else np.zeros_like(a)
+
+    monkeypatch.setattr(StreamingWeightedSum, "_shard_acc", first_only)
+
+
 def eval_half_batch(monkeypatch):
     """The evaluate's loss taken over half of the batch's rows."""
     from repro.train import steps

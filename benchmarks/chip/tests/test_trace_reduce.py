@@ -133,3 +133,36 @@ def test_readers_with_nothing_to_read_return_none(ctx):
         assert readings.read(name, bare) is None
     no_round = readings.Context(tr.Trace(), {}, ctx.mix, ctx.peaks, 1.0, 1.0)
     assert readings.read("round_mfu", no_round) is None
+
+
+@pytest.fixture(scope="module")
+def four_chip_trace():
+    """One logical train step on four chips: a ``train_step`` program
+    event on each device, of 180, 170, 160 and 150 ns, in a 1000 ns
+    round."""
+    planes = [_plane(i + 1, f"/device:TPU:{i}", {
+        "XLA Ops": [("%fusion.1 = bf16[8]{0} fusion()", 120, 200)],
+        "XLA Modules": [("jit_train_step(123)", 110, 290 - 10 * i)]})
+        for i in range(4)]
+    text = "\n".join(planes + [_plane(9, "/host:CPU", {"python3": HOST})])
+    return tr.from_profile(ProfileData.from_text_proto(text))
+
+
+@pytest.mark.parametrize("chips, fit_mfu, round_mfu", [
+    # 4 events / 4 chips = 1 step of 0.0066 FLOP over the events' 660 ns
+    # at 1e5 FLOP/s; 0.008 FLOP over a 1000 ns round x 4 chips x 1e5
+    (4, 10.0, 2.0),
+    # one chip's arithmetic, as before chips were counted: 4 steps over
+    # 660 ns, the round over one chip's peak
+    (1, 40.0, 8.0)])
+def test_mfu_readers_count_the_chips(four_chip_trace, chips, fit_mfu,
+                                     round_mfu):
+    c = readings.Context(
+        trace=four_chip_trace, cfg={}, mix={"sites": 1, "local_steps": 1},
+        peaks={"bf16_flops": 1e5, "hbm_bytes_per_s": 1e12},
+        fit_flops_per_round=0.0066, eval_flops_per_round=0.0014,
+        chips=chips)
+    assert readings.read("fit_step_mfu", c) == pytest.approx(fit_mfu)
+    assert readings.read("round_mfu", c) == pytest.approx(round_mfu)
+    # a mean over the chips: 80 ns of 1000 busy on each
+    assert readings.read("idle_frac", c) == pytest.approx(92.0)

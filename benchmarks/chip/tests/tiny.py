@@ -1,8 +1,10 @@
-"""The benchmark's two cells cut to a size the CPU runs in seconds:
-widths shrunk to 256, the sites, three local steps (all recorded), the
-routing geometry (groups of 64 tokens, capacity 20, top-8 of 32 experts)
-and the wire codec kept.  At this size the sound program stays inside the
-cells' chip-calibrated limits and the float8 control does not."""
+"""The benchmark's cells cut to a size the CPU runs in seconds: widths
+shrunk to 256, the sites, three local steps (all recorded), the routing
+geometry (groups of 64 tokens, capacity 20, top-8 of 32 experts), the
+wire codec and the device layout kept (a four-chip cell on four virtual
+CPU devices, ``XLA_FLAGS=--xla_force_host_platform_device_count=4``).
+At this size the sound program stays inside the cells' chip-calibrated
+limits and the float8 control does not."""
 import copy
 
 import run
@@ -32,5 +34,6 @@ def cell(workload: str) -> dict:
     else:
         cfg.update(intermediate_size=512, sliding_window=32)
         rep.update(d_ff=512, window=32)
-        mix.update(batch=4)
+        # 4 rows a chip, as the cells' 16 rows on four chips
+        mix.update(batch=4 * c["workload"]["chips"])
     return c
