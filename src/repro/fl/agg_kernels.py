@@ -30,7 +30,8 @@ buffers) and :class:`~repro.fl.flat.QuantParams` (int8/bf16 compressed
 wire payloads) implement.  For quantized inputs the dequantize + scale
 (+ delta-base add) is **fused into the per-chunk read**, so accumulators
 consume compressed buffers directly — peak extra memory stays one
-CHUNK-sized fp64 scratch, never a model-size fp32 copy of the payload.
+CHUNK-sized fp64 scratch (one ``FINALIZE_BLOCK``-sized in the sharded
+finalize), never a model-size fp32 copy of the payload.
 
 NB (numpy>=2 / NEP 50): scalar weights MUST be ``np.float64`` — a bare
 python float is "weak" and would demote the multiply to the fp32 loop,
@@ -79,6 +80,11 @@ from repro.utils import tracing
 # 16K elements: chunk fp64 accumulator + scratch = 256 KiB, L2-resident.
 # QCHUNK (int8 scale window) divides CHUNK, so quantized reads stay aligned.
 CHUNK = 1 << 14
+# The sharded finalize's step (a multiple of QCHUNK): 32 MiB of fp64
+# scratch.  Each numpy call releases the GIL and waits for it back behind
+# the process's other Python threads, so the same bytes in CHUNK steps
+# would take 256x the calls, and as many waits.
+FINALIZE_BLOCK = 1 << 22
 
 _FLOATS = {"float16", "float32", "float64"}
 
@@ -895,7 +901,7 @@ class StreamingWeightedSum:
                     self._devices[si % len(self._devices)])
             else:
                 ctx = contextlib.nullcontext()
-            with ctx:
+            with ctx, tracing.span("repro.fold.kernel") as s:
                 # the base was recorded by _add_sharded: deferred
                 self._spad[si] = agg_reduce.weighted_sum(
                     src.data[None, :], wts,
@@ -903,6 +909,9 @@ class StreamingWeightedSum:
                     else src.scales[None, :],
                     qchunk=src.qchunk, acc=acc, block=self._block,
                     out_padded=True)
+                if s:
+                    tracing.annotate(s, clients=1, nbytes=src.data.nbytes + (
+                        0 if src.scales is None else src.scales.nbytes))
             self._sgeom[si] = geom
             self._sacc[si] = None
         return True
@@ -916,6 +925,16 @@ class StreamingWeightedSum:
     def _finalize_sharded(self) -> FlatParams:
         if self._pipe is not None:
             self._pipe.drain(self._fold_item)
+        live = [(si, lo, hi)
+                for si, (lo, hi) in enumerate(self._bounds) if hi > lo]
+        if any(self._spad[si] is not None for si, _, _ in live):
+            with tracing.span("repro.fold.kernel") as s:
+                # waits for each range's device fold, copies it back
+                for si, _, _ in live:
+                    self._shard_acc(si)
+                if s:
+                    tracing.annotate(s, clients=self.count, nbytes=sum(
+                        self._sacc[si].nbytes for si, _, _ in live))
         inv = np.float64(1.0 / self.total_w)
         # canonical token order: the deferred-base add is independent of
         # which client's delta arrived first
@@ -926,24 +945,31 @@ class StreamingWeightedSum:
         n = self.layout.total_size
         uniform = self.layout.uniform_dtype in _FLOATS
         ovec = out.math_view() if uniform else np.empty(n, np.float64)
-        # the one all-gather: each shard's acc/W (+ deferred (w_b/W) b,
-        # streamed chunk-wise so no model-size fp64 base materializes)
-        # lands in the output buffer
-        for si, (lo, hi) in enumerate(self._bounds):
-            if hi <= lo:
-                continue
-            a = self._shard_acc(si)
-            a *= inv
-            for c0 in range(lo, hi, CHUNK):
-                c1 = min(c0 + CHUNK, hi)
-                seg = a[c0 - lo:c1 - lo]
-                for bobj, bw in defs:
-                    x = bobj.f64_chunk(c0, c1, self._tmp)
-                    np.multiply(x, bw, out=self._scratch[:c1 - c0])
-                    seg += self._scratch[:c1 - c0]
-                ovec[c0:c1] = seg
-        if not uniform:
-            _scatter_leaves(ovec, self.layout, out)
+        with tracing.span("repro.fold.unstage") as s:
+            # the one all-gather: each shard's acc/W (+ deferred (w_b/W)
+            # b, streamed in FINALIZE_BLOCK pieces, so the base's fp64
+            # scratch is one block, never model-size) lands in the
+            # output buffer
+            tmp = np.empty(min(FINALIZE_BLOCK, max(
+                (hi - lo for _, lo, hi in live), default=0)), np.float64)
+            blocks = 0
+            for si, lo, hi in live:
+                a = self._shard_acc(si)
+                a *= inv
+                for c0 in range(lo, hi, FINALIZE_BLOCK):
+                    c1 = min(c0 + FINALIZE_BLOCK, hi)
+                    seg = a[c0 - lo:c1 - lo]
+                    for bobj, bw in defs:
+                        x = bobj.f64_chunk(c0, c1, tmp)
+                        x *= bw
+                        seg += x
+                    ovec[c0:c1] = seg
+                    blocks += 1
+            if not uniform:
+                _scatter_leaves(ovec, self.layout, out)
+            if s:
+                tracing.annotate(s, clients=self.count, nbytes=n * 8,
+                                 blocks=blocks)
         return out
 
 

@@ -367,3 +367,118 @@ def test_fedavg_end_to_end_sharded_matches_streaming():
         np.testing.assert_array_equal(g, w)
     for g, w in zip(sharded, deferred):
         assert ulp_diff(g, w) <= 1
+
+
+# ---------------------------------------------------------------------------
+# sharded finalize: the deferred base added block by block
+# ---------------------------------------------------------------------------
+def _dequant(q, sc):
+    """The codec's int8 dequantize: ``q * scale`` in fp64, rounded once
+    through fp32."""
+    x = q.astype(np.float64) * np.repeat(sc.astype(np.float64),
+                                         QCHUNK)[:q.size]
+    return x.astype(np.float32).astype(np.float64)
+
+
+def _q8_round(n, n_bases, seed, clients=4):
+    """q8 delta arrivals as a round hands them to the fold: ``[(payload,
+    weight)]``, client k against base ``k % n_bases`` (q8 downlinks)."""
+    layout = layout_for([("float32", (n,))])
+    rng = np.random.default_rng(seed)
+    bases = [QuantParams(layout, "q8", *quantize_int8(
+        rng.normal(0, 0.5, n).astype(np.float32))) for _ in range(n_bases)]
+    arrivals = []
+    for k in range(clients):
+        q, sc = quantize_int8(rng.normal(0, 1e-3 * (1 + k), n)
+                              .astype(np.float32))
+        arrivals.append((QuantParams(layout, "q8", q, sc, is_delta=True,
+                                     base=bases[k % n_bases]),
+                         float(10 + 3 * k)))
+    return layout, arrivals
+
+
+def _chunk_step_finalize(n, shards, arrivals):
+    """The sharded numpy fold of ``arrivals``, finalized in ``CHUNK``
+    steps, written as a plain loop: per range the deltas' weighted sum in
+    arrival order, times 1/W, then per chunk each base at (summed weight
+    / W) in token order, cast to fp32."""
+    total_w = 0.0
+    base_w, base_of = {}, {}
+    for fp, w in arrivals:
+        total_w += w
+        tok = memo_token(fp.base)
+        base_w[tok] = base_w.get(tok, 0.0) + w
+        base_of[tok] = fp.base
+    deltas = [(_dequant(fp.data, fp.scales), np.float64(w))
+              for fp, w in arrivals]
+    bases = [(_dequant(base_of[t].data, base_of[t].scales),
+              np.float64(base_w[t] / total_w)) for t in sorted(base_w)]
+    out = np.empty(n, np.float32)
+    for lo, hi in shard_bounds(n, shards, align=QCHUNK):
+        acc = np.zeros(hi - lo)
+        for d, w in deltas:
+            acc += d[lo:hi] * w
+        acc *= np.float64(1.0 / total_w)
+        for c0 in range(lo, hi, K.CHUNK):
+            c1 = min(c0 + K.CHUNK, hi)
+            seg = acc[c0 - lo:c1 - lo]
+            for b, bw in bases:
+                seg += b[c0:c1] * bw
+            out[c0:c1] = seg
+    return out
+
+
+@pytest.mark.parametrize("block,n", [
+    (None, K.FINALIZE_BLOCK + 3 * QCHUNK + 77),
+    (3 * QCHUNK, 40 * QCHUNK + 77),     # many blocks a range, ragged ends
+])
+@pytest.mark.parametrize("n_bases", [1, 2])
+@pytest.mark.parametrize("shards", [1, 2, 4])
+def test_sharded_finalize_blocks_bitwise_chunk_steps(monkeypatch, shards,
+                                                     n_bases, block, n):
+    """Finalizing in FINALIZE_BLOCK pieces gives bitwise the result of
+    the CHUNK-step finalize, for one and for two deferred bases, on a
+    length that is a multiple of neither the block nor QCHUNK."""
+    if block is not None:
+        monkeypatch.setattr(K, "FINALIZE_BLOCK", block)
+    assert n % K.FINALIZE_BLOCK and n % QCHUNK
+    layout, arrivals = _q8_round(n, n_bases, seed=40 + shards)
+    s = K.StreamingWeightedSum(layout, backend="numpy", shards=shards,
+                               overlap=False)
+    for fp, w in arrivals:
+        s.add(fp, w)
+    got = s.finalize().math_view()
+    want = _chunk_step_finalize(n, shards, arrivals)
+    np.testing.assert_array_equal(got.view(np.uint32),
+                                  want.view(np.uint32))
+
+
+def test_sharded_finalize_reads_each_base_once_a_block(monkeypatch):
+    """The finalize reads each deferred base at most ceil(range /
+    FINALIZE_BLOCK) times a range: a count, so it holds on any host."""
+    n = 2 * K.FINALIZE_BLOCK + 3 * QCHUNK + 3
+    layout, arrivals = _q8_round(n, 2, seed=47)
+    s = K.StreamingWeightedSum(layout, backend="numpy", shards=2,
+                               overlap=False)
+    for fp, w in arrivals:
+        s.add(fp, w)
+    reads = {}
+    f64_chunk = QuantParams.f64_chunk
+
+    def counting(self, lo, hi, out):
+        key = (memo_token(self), lo)
+        reads[key] = reads.get(key, 0) + 1
+        return f64_chunk(self, lo, hi, out)
+
+    monkeypatch.setattr(QuantParams, "f64_chunk", counting)
+    s.finalize()
+    bounds = shard_bounds(n, 2, align=QCHUNK)
+    per = {}
+    for (tok, lo), k in reads.items():
+        si = next(i for i, (a, b) in enumerate(bounds) if a <= lo < b)
+        per[tok, si] = per.get((tok, si), 0) + k
+    toks = {memo_token(fp.base) for fp, _ in arrivals}
+    assert set(per) == {(t, si) for t in toks for si in range(2)}
+    for (_, si), k in per.items():
+        lo, hi = bounds[si]
+        assert k <= -(-(hi - lo) // K.FINALIZE_BLOCK), (si, k)

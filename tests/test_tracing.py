@@ -221,3 +221,39 @@ def test_the_native_path_gets_the_serve_span(tmp_path):
         "register", "pull_task_ins", "pull_task_ins"]
     assert ["queued_s" in a for a in served] == [False, False, True]
     assert served[2]["queued_s"] >= 0
+
+
+def test_the_sharded_fold_gets_kernel_and_unstage_spans(tmp_path):
+    """A fold over two accumulator ranges on the Pallas backend: one
+    ``repro.fold.kernel`` for each range of each arrival, one around the
+    finalize's copies back, and one ``repro.fold.unstage`` that counts
+    its blocks."""
+    from repro.fl.agg_kernels import StreamingWeightedSum
+    from repro.fl.flat import QuantParams, layout_for, quantize_int8
+
+    n = 5000
+    layout = layout_for([("float32", (n,))])
+    rng = np.random.default_rng(5)
+    base = QuantParams(layout, "q8", *quantize_int8(
+        rng.normal(0, 0.5, n).astype(np.float32)))
+    s = StreamingWeightedSum(layout, backend="pallas", shards=2,
+                             overlap=False)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        for k in range(3):
+            q, sc = quantize_int8(rng.normal(0, 1e-3, n).astype(np.float32))
+            s.add(QuantParams(layout, "q8", q, sc, is_delta=True,
+                              base=base), 1.0 + k)
+        s.finalize()
+    prof = ProfileData.from_file(
+        glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))[0])
+    spans = [e for _, e in program_spans.host_events(prof)
+             if e.name.startswith("repro.fold.")]
+    kernel = [e.stats for e in spans if e.name == "repro.fold.kernel"]
+    assert [a["clients"] for a in kernel] == [1] * 6 + [3]
+    assert kernel[-1]["nbytes"] == n * 8
+    unstage = [e.stats for e in spans if e.name == "repro.fold.unstage"]
+    assert len(unstage) == 1
+    assert (unstage[0]["clients"], unstage[0]["nbytes"],
+            unstage[0]["blocks"]) == (3, n * 8, 2)
