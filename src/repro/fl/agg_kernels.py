@@ -66,14 +66,16 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import dataclasses
 import os
 import queue
 import threading
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.fl.flat import FlatParams, Layout, memo_token, np_dtype
+from repro.fl.flat import (FlatParams, Layout, TileSource, memo_token,
+                           np_dtype)
 from repro.kernels.platform import on_tpu
 from repro.utils import tracing
 
@@ -205,34 +207,77 @@ def resolve_shards(shards: Optional[int], mesh=None) -> int:
     return int(sizes.get("data", mesh.devices.size))
 
 
-def _tile_stack(flats: Sequence) -> Optional[Dict[str, Any]]:
-    """Stack per-client :class:`~repro.fl.flat.TileSource` adapters into
-    the (C, N) host arrays the Pallas kernels consume, or count the
+class Staged(NamedTuple):
+    """One payload's fold operands, as :func:`stage` leaves them."""
+
+    #: the payload's TileSource, its ``data`` and ``scales`` copied to the
+    #: default device; ``None`` when the payload has no Pallas fold
+    src: Optional[TileSource]
+    #: the :data:`FALLBACK_RULES` key of a payload with no Pallas fold
+    reason: Optional[str]
+    #: a delta's round base in fp64, one vector for all its deltas
+    base: Optional[np.ndarray]
+
+
+def stage(fp, bases: Dict[str, np.ndarray]) -> Staged:
+    """Copy one payload's fold operands to the default device (the copies
+    run asynchronously) and materialize a delta's round base in fp64,
+    once per base: ``bases`` holds each by :func:`memo_token`.  A payload
+    with no Pallas fold is not copied; the fold counts its reason."""
+    src, reason = _device_source(fp)
+    if src is None:
+        return Staged(None, reason, None)
+    import jax
+
+    with tracing.span("repro.fold.stage") as s:
+        nbytes = src.data.nbytes
+        scales = None
+        if src.scales is not None:
+            scales = jax.device_put(src.scales)
+            nbytes += src.scales.nbytes
+        src = dataclasses.replace(src, data=jax.device_put(src.data),
+                                  scales=scales)
+        base = None
+        if src.base is not None:
+            tok = memo_token(src.base)
+            base = bases.get(tok)
+            if base is None:
+                base = bases[tok] = src.base.to_f64()
+                nbytes += base.nbytes
+        if s:
+            tracing.annotate(s, clients=1, nbytes=nbytes)
+    return Staged(src, None, base)
+
+
+def _stage_all(flats: Sequence) -> List[Staged]:
+    bases: Dict[str, np.ndarray] = {}
+    return [stage(fp, bases) for fp in flats]
+
+
+def _tile_stack(staged: Sequence[Staged]) -> Optional[Dict[str, Any]]:
+    """The Pallas kernels' operands of staged payloads (per-client device
+    vectors, stacked by the kernel wrappers on the device), or count the
     :data:`FALLBACK_RULES` reason and return ``None`` (numpy path).  A
     delta stack's shared base comes back as fp64 for the caller to add
     (the kernels never see it)."""
-    sources = []
-    for fp in flats:
-        src, reason = _device_source(fp)
-        if src is None:
-            _fallback(reason)
+    for t in staged:
+        if t.src is None:
+            _fallback(t.reason)
             return None
-        sources.append(src)
+    sources = [t.src for t in staged]
     first = sources[0]
     if any(s.kind != first.kind or s.qchunk != first.qchunk
            or s.data.dtype != first.data.dtype for s in sources):
         _fallback("mixed")
         return None
-    bases = {id(s.base): s.base for s in sources}
-    if len(bases) > 1:
+    if len({id(s.base) for s in sources}) > 1:
         _fallback("multi_base")
         return None
-    base_obj = next(iter(bases.values()))
     q8 = first.kind == "q8"
-    return {"data": np.stack([s.data for s in sources]),
-            "scales": np.stack([s.scales for s in sources]) if q8 else None,
+    return {"data": [s.data for s in sources],
+            "scales": [s.scales for s in sources] if q8 else None,
             "qchunk": first.qchunk,
-            "base": base_obj.to_f64() if base_obj is not None else None}
+            "base": staged[0].base}
 
 
 def _scatter_leaves(vec: np.ndarray, layout: Layout,
@@ -258,17 +303,20 @@ def _vec_to_flat(vec: np.ndarray, layout: Layout) -> FlatParams:
 
 def weighted_mean(pairs: Sequence[Tuple[FlatParams, float]],
                   layout: Layout, backend: Optional[str] = None,
-                  block: Optional[int] = None) -> FlatParams:
+                  block: Optional[int] = None,
+                  staged: Optional[Sequence[Staged]] = None) -> FlatParams:
     """sum((w_i / W) x_i) over flat buffers -> FlatParams of ``layout``.
 
     Chunk-outer / client-inner: the fp64 accumulator chunk is reused across
     clients and cast straight into the output buffer, so no total-size fp64
-    array is ever materialized.
+    array is ever materialized.  ``staged``: the pairs' operands, in their
+    order, as :func:`stage` left them on each arrival (the Pallas fold
+    consumes them); without it the Pallas fold stages every pair here.
     """
     out = FlatParams.zeros(layout)
     if layout.total_size == 0 or not pairs:
         return out
-    vec = _weighted_mean_pallas(pairs, backend, block)
+    vec = _weighted_mean_pallas(pairs, backend, block, staged)
     if vec is not None:
         with tracing.span("repro.fold.unstage") as s:
             out = _vec_to_flat(vec, layout)
@@ -308,28 +356,28 @@ def _scaled_weights(pairs) -> List[np.float64]:
 
 
 def _weighted_mean_pallas(pairs, backend: Optional[str],
-                          block: Optional[int]) -> Optional[np.ndarray]:
+                          block: Optional[int],
+                          staged: Optional[Sequence[Staged]] = None
+                          ) -> Optional[np.ndarray]:
     if resolve_backend(backend) != "pallas":
         return None
-    with tracing.span("repro.fold.stage") as s:
-        stack = _tile_stack([fp for fp, _ in pairs])
-        if s and stack is not None:
-            tracing.annotate(s, clients=len(pairs), nbytes=sum(
-                a.nbytes for a in (stack["data"], stack["scales"],
-                                   stack["base"]) if a is not None))
+    on_arrival = len(staged) if staged is not None else 0
+    stack = _tile_stack(staged if staged is not None
+                        else _stage_all([fp for fp, _ in pairs]))
     if stack is None:
         return None
     from repro.kernels import agg_reduce
 
     scaled = _scaled_weights(pairs)
+    nbytes = sum(a.nbytes for a in stack["data"] + (stack["scales"] or []))
     with tracing.span("repro.fold.kernel") as s:
+        # the (C, rows, lanes) stack and its padding form on the device
         vec = agg_reduce.weighted_sum(
             stack["data"], np.array(scaled, np.float64),
             scales=stack["scales"], qchunk=stack["qchunk"], block=block)
         if s:
-            tracing.annotate(s, clients=len(pairs), nbytes=sum(
-                a.nbytes for a in (stack["data"], stack["scales"])
-                if a is not None))
+            tracing.annotate(s, clients=len(pairs), nbytes=nbytes,
+                             staged=on_arrival)
     if stack["base"] is not None:
         with tracing.span("repro.fold.unstage") as s:
             # deferred delta base: sum s_i (d_i + b) == sum s_i d_i + S b
@@ -985,7 +1033,7 @@ def _sorted_reduce_pallas(flats, layout, kind: str, trim_k: int,
                           block: Optional[int]) -> Optional[FlatParams]:
     """Shared Pallas branch of the sort-based reductions; ``None`` means
     "fall back to numpy" (counted by :func:`_tile_stack`)."""
-    stack = _tile_stack(flats)
+    stack = _tile_stack(_stage_all(flats))
     if stack is None:
         return None
     from repro.kernels import agg_reduce
@@ -1065,7 +1113,7 @@ def krum_distances(flats: Sequence[FlatParams], layout: Layout,
     n_clients = len(flats)
     if layout.total_size and flats \
             and resolve_backend(backend) == "pallas":
-        stack = _tile_stack(flats)
+        stack = _tile_stack(_stage_all(flats))
         if stack is not None:
             from repro.kernels import agg_reduce
 

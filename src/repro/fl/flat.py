@@ -330,6 +330,11 @@ def unflatten_vector(vec: np.ndarray, layout: Layout) -> NDArrays:
 # ---------------------------------------------------------------------------
 QCHUNK = 1024        # elements per int8 scale chunk (fp32 scale each)
 _QBLOCK = 1 << 20    # elements per quantize/dequantize pass (QCHUNK-aligned)
+#: elements per pass of a q8 vector's whole fp64 materialization
+#: (``QuantParams.to_f64``, QCHUNK-aligned).  Each numpy call of a pass
+#: releases the GIL and waits for it back behind the process's other
+#: Python threads: a server stages a round base while its sites still work.
+_F64_BLOCK = 1 << 22
 #: elements per device quantize call (QCHUNK-aligned): 64 MiB of fp32 in,
 #: 16 MiB of int8 out.  Vectors this long or longer quantize on the device.
 SLAB = 1 << 24
@@ -612,8 +617,8 @@ class QuantParams:
         n = self.layout.total_size
         if out is None:
             out = np.empty(n, np.float64)
-        for lo in range(0, n, _QBLOCK):
-            hi = min(lo + _QBLOCK, n)
+        for lo in range(0, n, _F64_BLOCK):
+            hi = min(lo + _F64_BLOCK, n)
             self.f64_chunk(lo, hi, out[lo:hi])
         return out
 

@@ -182,14 +182,19 @@ class _WeightedFitAcc(FitAccumulator):
 
     Default mode keeps zero-copy FlatParams references (no per-layer
     stacking; memory is just the already-received payload bytes) and runs
-    the bitwise-legacy-exact deferred kernel at finalize.  ``low_memory``
-    folds each result into one fp64 accumulator on arrival instead, so
-    peak memory is a single model-size vector.
+    the bitwise-legacy-exact deferred kernel at finalize.  On the Pallas
+    backend each arrival's operands go to the device as it arrives
+    (:func:`~repro.fl.agg_kernels.stage`), while the other sites still
+    fit, so the finalize only stacks them there and runs the kernel.
+    ``low_memory`` folds each result into one fp64 accumulator on arrival
+    instead, so peak memory is a single model-size vector.
     """
 
     def __init__(self, strategy: "FedAvg", rnd: int, current: NDArrays):
         super().__init__(strategy, rnd, current)
-        self.pairs: List[Tuple[str, FlatParams, float]] = []
+        # (node, payload, weight, its staged operands or None)
+        self.pairs: List[Tuple[str, FlatParams, float, Any]] = []
+        self._bases: Dict[str, np.ndarray] = {}     # staged round bases
         self.partials: List[Tuple[str, Any]] = []   # (node, PartialSum)
         self.sparses: List[Tuple[str, Any, float]] = []  # (node, SparseDelta, w)
         self._streaming: Optional[kernels.StreamingWeightedSum] = None
@@ -238,7 +243,11 @@ class _WeightedFitAcc(FitAccumulator):
                 self._streaming = self._make_streaming(fp.layout)
             self._streaming.add(fp, w)      # payload is droppable after this
         else:
-            self.pairs.append((node, fp, w))
+            staged = None
+            if kernels.resolve_backend(st.backend) == "pallas" \
+                    and fp.layout.total_size:
+                staged = kernels.stage(fp, self._bases)
+            self.pairs.append((node, fp, w, staged))
         self._count += 1        # only after the fold/append succeeded
         self._payloads += 1
 
@@ -264,9 +273,8 @@ class _WeightedFitAcc(FitAccumulator):
                           else self.sparses[0][1].layout)
                 self._streaming = self._make_streaming(layout)
             self.pairs.sort(key=lambda p: p[0])
-            for _, fp, w in self.pairs:
+            for _, fp, w, _ in self.pairs:
                 self._streaming.add(fp, w)
-            self.pairs = []
             self.sparses.sort(key=lambda s: s[0])
             for _, sp, w in self.sparses:
                 self._streaming.add_sparse(sp, w)
@@ -279,9 +287,14 @@ class _WeightedFitAcc(FitAccumulator):
         else:
             # canonical node order -> aggregate independent of arrival order
             self.pairs.sort(key=lambda p: p[0])
-            pairs = [(fp, w) for _, fp, w in self.pairs]
-            target = kernels.weighted_mean(pairs, pairs[0][0].layout,
-                                           backend=st.backend)
+            pairs = [(fp, w) for _, fp, w, _ in self.pairs]
+            staged = [t for _, _, _, t in self.pairs]
+            target = kernels.weighted_mean(
+                pairs, pairs[0][0].layout, backend=st.backend,
+                staged=None if staged[0] is None else staged)
+        # the fold consumed or dropped the staged operands
+        self.pairs = []
+        self._bases = {}
         metrics = {"num_clients": self._count,
                    "num_payloads": self._payloads}
         sub_failures = sorted(

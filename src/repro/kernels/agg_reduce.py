@@ -30,7 +30,9 @@ as (rows, lanes); the kernels take the stacked (C, rows, lanes) array in
 (C, tr, lanes) blocks, so the last two block dimensions obey the TPU's
 (8, 128) tiling rule (``tr`` is a multiple of 32, the int8 sublane packing,
 or all the rows).  ``lanes`` divides the int8 scale window ``qchunk``, so
-every row has one scale: the scales travel as a (C, rows, 1) array.
+every row has one scale: the scales travel as a (C, rows, 1) array.  The
+wrappers take that stack from the host, padding it there, or as C
+per-client vectors already on the device, padding it there.
 
 Delta payloads are never reconstructed here: a delta's shared round base
 is added by the caller at finalize (``sum w_k (d_k + b) == sum w_k d_k +
@@ -118,18 +120,60 @@ def _as_rows(a: np.ndarray, geom: Geometry, fill=0) -> np.ndarray:
     return a.reshape(a.shape[:-1] + (rows, lanes))
 
 
-def _data_rows(data: np.ndarray, geom: Geometry) -> np.ndarray:
-    if data.dtype == np.float64:
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _device_rows(vecs, repeat: int, shape: Tuple[int, int], fill):
+    """The (C, *shape) operand :func:`_as_rows` (``repeat`` 1) or
+    :func:`_scale_rows` (``repeat`` rows a window) builds on the host,
+    formed on the device from C per-client vectors.  Each is written into
+    its row of the filled stack in place: a stack of the flat vectors,
+    padded and then reshaped, costs the chip's compiler several temporary
+    copies of the whole stack to relayout."""
+    rows, lanes = shape
+    dtype = jnp.float32 if vecs[0].dtype == jnp.float16 else vecs[0].dtype
+    out = jnp.full((len(vecs),) + shape, fill, dtype)
+    for i, v in enumerate(vecs):
+        v = jnp.repeat(v.astype(dtype), repeat)[:rows * lanes]
+        full, rem = divmod(v.shape[0], lanes)
+        out = out.at[i, :full].set(v[:full * lanes].reshape(full, lanes))
+        if rem:
+            out = out.at[i, full, :rem].set(v[full * lanes:])
+    return out
+
+
+def _stacked(vecs, repeat: int, shape: Tuple[int, int], fill):
+    """:func:`_device_rows` of staged vectors, which it consumes: each is
+    deleted once the stack is formed, so the fold holds the stack and
+    not the client copies too."""
+    out = _device_rows(tuple(vecs), repeat, shape, fill)
+    for v in vecs:
+        v.delete()
+    return out
+
+
+def _shape_of(data) -> Tuple[int, int]:
+    """(C, N) of a host (C, N) stack or of C staged (N,) device vectors."""
+    if isinstance(data, np.ndarray):
+        return data.shape
+    return len(data), int(data[0].shape[0])
+
+
+def _data_rows(data, geom: Geometry):
+    if data[0].dtype == np.float64:
         raise TypeError("fp64 payloads have no on-chip fold: the dispatch "
                         "layer routes them to numpy (see agg_kernels)")
+    if not isinstance(data, np.ndarray):
+        lanes, _, rows = geom
+        return _stacked(data, 1, (rows, lanes), 0)
     if data.dtype == np.float16:
         data = data.astype(np.float32)  # exact; Mosaic has no f16 VPU path
     return _as_rows(data, geom)
 
 
-def _scale_rows(scales: np.ndarray, qchunk: int, geom: Geometry):
+def _scale_rows(scales, qchunk: int, geom: Geometry):
     """(C, nchunks) per-window scales -> one scale per tile row."""
     lanes, _, rows = geom
+    if not isinstance(scales, np.ndarray):
+        return _stacked(scales, qchunk // lanes, (rows, 1), 1)
     per_row = np.repeat(np.asarray(scales, np.float32), qchunk // lanes,
                         axis=-1)[..., :rows]
     return _as_rows(per_row, (1, 0, geom[2]), fill=1)   # (C, rows, 1)
@@ -235,26 +279,28 @@ def wsum_fn(geom: Geometry, clients: int, q8: bool, has_acc: bool,
         interpret=interpret, name="agg_weighted_sum"))
 
 
-def weighted_sum(data: np.ndarray, weights: np.ndarray, *,
-                 scales: Optional[np.ndarray] = None,
+def weighted_sum(data, weights: np.ndarray, *,
+                 scales=None,
                  qchunk: int = DEFAULT_QCHUNK,
                  acc=None,
                  block: Optional[int] = None,
                  out_padded: bool = False):
     """``(acc +) sum_c weights[c] * decode(data[c])`` as one fused pass.
 
-    ``data``: (C, N) fp32/bf16/fp16 or int8 (with ``scales`` (C, S)).
-    ``acc``: the running accumulator — an (N,) fp64 host vector, or the
-    ``(hi, lo)`` device pair a previous ``out_padded=True`` call returned
+    ``data``: (C, N) fp32/bf16/fp16 or int8 (with ``scales`` (C, S)) on
+    the host, or C staged (N,) device vectors (with C (S,) scales), whose
+    padded stack is formed on the device and which are deleted once it
+    is.  ``acc``: the running accumulator — an (N,) fp64 host vector, or
+    the ``(hi, lo)`` device pair a previous ``out_padded=True`` call returned
     under the same :func:`geometry` (so successive arrivals form one
     asynchronous dispatch chain with no host round-trip).  Returns the
     (N,) fp64 host vector, or with ``out_padded=True`` the padded device
     pair (read it with :func:`pair_to_host`).
     """
-    c, n = data.shape
+    c, n = _shape_of(data)
     if n == 0:
         return np.zeros(0, np.float64) if acc is None else np.asarray(acc)
-    q8 = data.dtype == np.int8
+    q8 = data[0].dtype == np.int8
     geom = geometry(n, c, qchunk if q8 else None, block)
     args = [_split_weights(weights)]
     if acc is not None:
@@ -336,12 +382,13 @@ def sort_fn(geom: Geometry, clients: int, q8: bool, kind: str, trim_k: int,
         interpret=interpret, name=f"agg_sort_{kind}"))
 
 
-def sort_reduce(data: np.ndarray, *, kind: str = "median", trim_k: int = 0,
-                scales: Optional[np.ndarray] = None,
+def sort_reduce(data, *, kind: str = "median", trim_k: int = 0,
+                scales=None,
                 qchunk: int = DEFAULT_QCHUNK,
                 base: Optional[np.ndarray] = None,
                 block: Optional[int] = None) -> np.ndarray:
     """Coordinate-wise sorted reduction over the (C, N) stack -> (N,) fp64.
+    ``data`` and ``scales`` as :func:`weighted_sum` takes them.
 
     ``kind="median"`` returns the per-coordinate median;
     ``kind="trim_sum"`` the per-coordinate SUM of the sorted rows
@@ -351,10 +398,10 @@ def sort_reduce(data: np.ndarray, *, kind: str = "median", trim_k: int = 0,
     """
     if kind not in ("median", "trim_sum"):
         raise ValueError(f"kind {kind!r}")
-    c, n = data.shape
+    c, n = _shape_of(data)
     if n == 0:
         return np.zeros(0, np.float64)
-    q8 = data.dtype == np.int8
+    q8 = data[0].dtype == np.int8
     geom = geometry(n, c, qchunk if q8 else None, block)
     args = [_data_rows(data, geom)]
     if q8:
@@ -411,17 +458,18 @@ def gram_fn(geom: Geometry, clients: int, q8: bool, interpret: bool):
         interpret=interpret, name="agg_gram"))
 
 
-def gram(data: np.ndarray, *,
-         scales: Optional[np.ndarray] = None,
+def gram(data, *,
+         scales=None,
          qchunk: int = DEFAULT_QCHUNK,
          block: Optional[int] = None) -> np.ndarray:
-    """(C, C) fp64 Gram matrix of the row-0-centered client stack.  A
+    """(C, C) fp64 Gram matrix of the row-0-centered client stack
+    (``data`` and ``scales`` as :func:`weighted_sum` takes them).  A
     shared delta base cancels in the centering, so deltas need none.
     Each grid step returns its fp32 partial; the host sums them in fp64."""
-    c, n = data.shape
+    c, n = _shape_of(data)
     if n == 0:
         return np.zeros((c, c), np.float64)
-    q8 = data.dtype == np.int8
+    q8 = data[0].dtype == np.int8
     geom = geometry(n, c, qchunk if q8 else None, block,
                     max_rows=_GRAM_ROWS)
     args = [_data_rows(data, geom)]

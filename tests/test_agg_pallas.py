@@ -15,6 +15,8 @@ the tests that draw such sums check that bound.  Krum's Gram matrix is an
 fp32 matmul, so its *distances* carry an fp32 relative tolerance while
 the selection and the final aggregate stay within the fold's contract.
 """
+import itertools
+
 import numpy as np
 import pytest
 
@@ -22,6 +24,8 @@ try:
     from hypothesis import given, settings, strategies as st
 except ImportError:                      # bare env: deterministic shim
     from _hypothesis_fallback import given, settings, strategies as st
+
+import jax
 
 from repro.fl import agg_kernels as K
 from repro.fl.flat import (FlatParams, QuantParams, layout_for, np_dtype,
@@ -237,6 +241,90 @@ def test_streaming_fold_mixed_backends_is_exact():
             s_mix.backend = "pallas"
         s_mix.add(fp, 2.0 + i)
     assert_flat_ulp(s_mix.finalize(), s_np.finalize())
+
+
+# ---------------------------------------------------------------------------
+# FedAvg's accumulator: operands staged on the device as each result arrives
+# ---------------------------------------------------------------------------
+SITES4 = ("site-1", "site-2", "site-3", "site-4")
+
+
+def _fedavg_fold(layout, flats, weights, order):
+    """FedAvg's accumulator on the Pallas backend, fed ``flats[i]`` (as
+    site ``SITES4[i]``'s fit result) in ``order``; the new model, and
+    whether each arrival's operands were on the device before finalize."""
+    from repro.fl.messages import FitRes
+    from repro.fl.strategy import FedAvg
+
+    acc = FedAvg(backend="pallas").fit_accumulator(
+        1, [np.zeros(s.shape, np_dtype(s.dtype)) for s in layout.leaves])
+    for i in order:
+        fp = flats[i]
+        res = (FitRes(None, weights[i], quant=fp)
+               if isinstance(fp, QuantParams)
+               else FitRes(None, weights[i], flat=fp))
+        acc.add(SITES4[i], res)
+    on_device = [t is not None and t.src is not None
+                 and isinstance(t.src.data, jax.Array)
+                 for *_, t in acc.pairs]
+    got, _ = acc.finalize([])
+    return got, on_device
+
+
+@pytest.mark.parametrize("order", list(itertools.permutations(range(4))))
+@pytest.mark.parametrize("codec", ["q8_delta_quant", "q8", "bf16", "flat"])
+def test_fedavg_folds_staged_arrivals_bitwise(codec, order):
+    """Each arrival's operands go to the device as it comes in; whatever
+    the arrival order, the finalize is bitwise the batch fold over the
+    same pairs in node order, with no numpy fallback."""
+    layout, flats = make_payloads("big_unaligned", codec, 4, seed=31)
+    weights = (3, 1, 4, 2)
+    K.reset_fallback_counts()
+    got, on_device = _fedavg_fold(layout, flats, weights, order)
+    assert on_device == [True] * 4
+    want = K.weighted_mean([(fp, float(w)) for fp, w in zip(flats, weights)],
+                           layout, backend="pallas").to_arrays()
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    assert K.fallback_counts() == {}
+
+
+@pytest.mark.parametrize("block", [512, 1024, 1 << 22])
+@pytest.mark.parametrize("codec", ["q8_delta_quant", "q8_delta_flat",
+                                   "bf16_delta"])
+def test_a_staged_round_base_is_its_f64_vector(monkeypatch, codec, block):
+    """The first arrival on a round base stages the base's fp64 vector,
+    whatever the passes a q8 base's ``to_f64`` makes, and every later
+    arrival on that base shares it."""
+    from repro.fl import flat
+
+    layout, flats = make_payloads("big_unaligned", codec, 2, seed=33)
+    monkeypatch.setattr(flat, "_F64_BLOCK", block)
+    bases = {}
+    first, second = (K.stage(fp, bases) for fp in flats)
+    base = flats[0].base
+    want = (flat.dequantize_int8(base.data, base.scales, base.qchunk)
+            if isinstance(base, QuantParams)
+            else base.math_view().astype(np.float64))
+    np.testing.assert_array_equal(first.base, want)
+    assert second.base is first.base and len(bases) == 1
+
+
+def test_fedavg_staged_fold_falls_back_once_on_a_mixed_arrival():
+    """A raw fp32 result after three q8 ones cannot join their stack: the
+    staged operands are dropped, the round folds on numpy, and one
+    ``mixed`` fallback is counted."""
+    layout, quants = make_payloads("big_unaligned", "q8", 3, seed=11)
+    _, raws = make_payloads("big_unaligned", "flat", 1, seed=12)
+    flats, weights = quants + raws, (2, 3, 4, 5)
+    K.reset_fallback_counts()
+    got, on_device = _fedavg_fold(layout, flats, weights, range(4))
+    assert on_device == [True] * 4
+    want = K.weighted_mean([(fp, float(w)) for fp, w in zip(flats, weights)],
+                           layout, backend="numpy").to_arrays()
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    assert K.fallback_counts() == {"mixed": 1}
 
 
 # ---------------------------------------------------------------------------

@@ -8,7 +8,8 @@ v5e chip, the kernels and the fit step that a federated round runs at
 
 - the fold kernels (``weighted_sum`` at ~126M elements for fp32 and int8
   payloads and the accumulator-continuation form, ``sort_reduce`` and
-  ``gram`` at 16 clients, the sparse dequant graph);
+  ``gram`` at 16 clients, the sparse dequant graph), and FedAvg's
+  finalize over payloads staged on the device at the danube cell's size;
 - ``flash_attention`` at 12 heads x 64 head dim x 256 tokens;
 - the wire codec's slab quantizer at ``repro.fl.flat.SLAB``;
 - the full-scale train step, whose compiled memory must fit 16 GB.
@@ -32,6 +33,8 @@ _ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 #: the --scale full model's parameter count, rounded up
 N_FULL = 126_000_000
+#: the h2o-danube-1.8b benchmark cell's parameter count
+N_DANUBE = 111_418_880
 HBM_BYTES = 16 * 1024 ** 3
 
 
@@ -97,6 +100,37 @@ def test_weighted_sum_compiles(one_chip, no_cache, payload):
     fn = A.wsum_fn(geom, clients, q8, payload == "acc", False)
     compiled = fn.lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_staged_fold_compiles_and_fits(one_chip, no_cache):
+    """A FedAvg finalize at the danube cell's size (4 sites' int8 payloads
+    of ``N_DANUBE`` elements, staged on the device as they arrived): the
+    on-device stack and padding of the payloads and of their scales, and
+    the kernel.  The staged vectors, the stacks and the ``(hi, lo)`` pair
+    fit one chip's HBM together."""
+    clients, qchunk = 4, A.DEFAULT_QCHUNK
+    geom = A.geometry(N_DANUBE, clients, qchunk)
+    lanes, _, rows = geom
+    data = tuple(_shape(one_chip, (N_DANUBE,), jnp.int8)
+                 for _ in range(clients))
+    scales = tuple(_shape(one_chip, (-(-N_DANUBE // qchunk),), jnp.float32)
+                   for _ in range(clients))
+    programs = [
+        A._device_rows.lower(data, 1, (rows, lanes), 0).compile(),
+        A._device_rows.lower(scales, qchunk // lanes, (rows, 1), 1)
+        .compile(),
+        A.wsum_fn(geom, clients, True, False, False).lower(
+            _shape(one_chip, (clients, 4), jnp.float32),
+            *_fold_operands(one_chip, geom, clients, jnp.int8, True))
+        .compile()]
+    assert "tpu_custom_call" in programs[-1].as_text()
+    used = 0
+    for compiled in programs:
+        mem = compiled.memory_analysis()
+        used += (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                 + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    # at least the int8 staged payloads, their stack and the fp32 pair
+    assert N_DANUBE * (2 * clients + 2 * 4) <= used < HBM_BYTES, used
 
 
 @pytest.mark.parametrize("kind", ["median", "trim_sum"])

@@ -159,7 +159,10 @@ def test_a_traced_federation_holds_every_span_with_its_args(traced):
                           and a["queued_s"] >= 0 for a in served)
     assert all(a["queued_s"] >= 0
                for a in by_name["repro.superlink.deliver"])
-    assert {a["clients"] for a in by_name["repro.fold.stage"]} == {2}
+    # each round stages its two arrivals one by one as they come in, and
+    # its finalize folds both staged operands
+    assert [a["clients"] for a in by_name["repro.fold.stage"]] == [1] * 4
+    assert [a["staged"] for a in by_name["repro.fold.kernel"]] == [2, 2]
 
 
 def test_every_q8_encode_names_its_quantizer_engine(traced):
